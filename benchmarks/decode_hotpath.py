@@ -162,6 +162,9 @@ def main(tiny: bool = False, steps: int | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true",
                     help="CI smoke: one config, no timing asserts")

@@ -107,5 +107,8 @@ def main() -> str:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import sys
     sys.exit(0 if main() == "ok" else 1)

@@ -247,6 +247,9 @@ def main(tiny: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true",
                     help="CI smoke mode: nvme only, smaller trace")

@@ -59,10 +59,12 @@ class ModelAdapter(Protocol):
         k_ctx: jax.Array,        # [B, N_sel, H_kv, d] assembled context K
         v_ctx: jax.Array,        # [B, N_sel, H_kv, d]
         ctx_mask: jax.Array,     # [B, N_sel] bool validity
+        use_pallas: bool = False,
     ):
         """One-token decode through block ``layer`` attending to the assembled
         context plus itself.  Returns ``(x_out [B, D], k_new [B, H_kv, d],
-        v_new [B, H_kv, d])``."""
+        v_new [B, H_kv, d])``.  ``use_pallas`` (static) routes the attention
+        through the Pallas flash-decode kernel."""
         ...
 
     def gather_context(

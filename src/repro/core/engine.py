@@ -372,7 +372,10 @@ class KVSwapEngine:
                 "modeled layer-pipelined decode-step latency")
             self._m_hist_wall = reg.histogram(
                 "kvswap_step_wall_seconds", "measured decode-step wall time")
-        self.compute_spec = hardware.COMPUTES.get(cfg.compute, hardware.TPU_V5E)
+        if cfg.compute not in hardware.COMPUTES:
+            raise ValueError(f"unknown compute {cfg.compute!r}; "
+                             f"expected one of {sorted(hardware.COMPUTES)}")
+        self.compute_spec = hardware.COMPUTES[cfg.compute]
         self.store = KVDiskStore(
             n_layers=n_kv_layers, batch=batch, max_groups=self.max_groups,
             group_size=g, n_kv_heads=model.n_kv_heads, head_dim=model.head_dim,
@@ -388,9 +391,6 @@ class KVSwapEngine:
             from repro.faults import FaultyDisk
 
             self.store = FaultyDisk(self.store, faults)
-        if cfg.use_pallas:
-            from repro.models import layers as _L
-            _L.set_use_pallas(True)
         mk = lambda: ReuseBuffer(
             batch=batch, capacity=cfg.reuse_capacity, group_size=g,
             n_kv_heads=model.n_kv_heads, head_dim=model.head_dim, dtype=cfg.np_dtype,
@@ -1215,6 +1215,7 @@ class KVSwapEngine:
         x, k_new, v_new = self.model.decode_block(
             self.params, layer, x, pos,
             jnp.asarray(k_ctx), jnp.asarray(v_ctx), jnp.asarray(tok_mask),
+            use_pallas=cfg.use_pallas,
         )
         completed = self.managers[j].append_token_rows(
             np.asarray(jax.device_get(k_new), dtype=cfg.np_dtype),
@@ -1274,7 +1275,8 @@ class KVSwapEngine:
                 k_ctx = k_ctx.at[bb, tt].set(jnp.asarray(np.concatenate(pay_k)))
                 v_ctx = v_ctx.at[bb, tt].set(jnp.asarray(np.concatenate(pay_v)))
         x, k_new, v_new = self.model.decode_block(
-            self.params, layer, x, pos, k_ctx, v_ctx, tok_mask)
+            self.params, layer, x, pos, k_ctx, v_ctx, tok_mask,
+            use_pallas=cfg.use_pallas)
         # scatter each active row's fresh token into its own tail position
         # (rows sit at different fills under continuous batching)
         act = jnp.asarray(self._step_active)
@@ -1399,12 +1401,10 @@ class KVSwapEngine:
         q32 = q_pred.astype(jnp.float32)
         if self.cfg.use_pallas:
             from repro.kernels import fused_predict_pallas
-            from repro.models import layers as _L
 
             return fused_predict_pallas(
                 q32, self._per_head_a, self.k_lr[layer], valid,
-                group_size=self.cfg.group_size, n_select=self.n_select,
-                interpret=_L.PALLAS_INTERPRET)
+                group_size=self.cfg.group_size, n_select=self.n_select)
         from repro.core.predictor import fused_predict
 
         return fused_predict(
@@ -1500,9 +1500,6 @@ class KVSwapEngine:
         if self.prefetcher is not None:
             self.prefetcher.close()
             self.prefetcher = None
-        if self.cfg.use_pallas:
-            from repro.models import layers as _L
-            _L.set_use_pallas(False)
         self.store.close()
 
     def __enter__(self):

@@ -1,8 +1,9 @@
 """Hardware constants + analytic compute-time model.
 
-The container is CPU-only; throughput benchmarks *model* compute time for the
-paper's evaluation platform (Jetson Orin AGX) and the dry-run roofline uses
-TPU v5e constants (the deployment target).
+These are *modeled* times, not measurements: the serving benchmarks price
+compute for the paper's evaluation platform (Jetson Orin AGX) and the dry-run
+roofline uses TPU v5e constants.  A time measured on a device comes from the
+host clock around work that ends in ``block_until_ready`` (``chip_smoke.py``).
 """
 
 from __future__ import annotations
@@ -35,8 +36,7 @@ ORIN_NANO = ComputeSpec("jetson-orin-nano", peak_flops=1.28e12, mem_bw=68e9)
 # ~50 GB/s per ICI link (constants fixed by the reproduction brief).
 TPU_V5E = ComputeSpec("tpu-v5e", peak_flops=197e12, mem_bw=819e9, link_bw=50e9)
 
-# Platform registry for ``EngineConfig.compute``; unknown names fall back to
-# TPU_V5E (the historical behavior for anything non-Jetson).
+# Platform registry for ``EngineConfig.compute``; an unknown name is an error.
 COMPUTES: dict[str, ComputeSpec] = {s.name: s for s in (ORIN, ORIN_NANO, TPU_V5E)}
 
 

@@ -22,8 +22,7 @@ from repro.core.predictor import lowrank_queries_per_head, select_groups
 from repro.kernels.ops import lowrank_group_scores
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("group_size", "n_select", "interpret"))
+@functools.partial(jax.jit, static_argnames=("group_size", "n_select"))
 def fused_predict_pallas(
     q: jax.Array,                 # [B, H, d] — fully-normed, RoPE'd query
     per_head_a: jax.Array,        # [H_k, d, r] — adapter.per_head
@@ -32,10 +31,8 @@ def fused_predict_pallas(
     *,
     group_size: int,
     n_select: int,
-    interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns device ``(group_ids [B, M], mask [B, M])``."""
     q_lr = lowrank_queries_per_head(q, per_head_a)
-    gs = lowrank_group_scores(q_lr, k_lr, valid_len, group_size=group_size,
-                              interpret=interpret)
+    gs = lowrank_group_scores(q_lr, k_lr, valid_len, group_size=group_size)
     return select_groups(gs, n_select)

@@ -10,16 +10,16 @@ decay/causal mask on the VPU, and a ``[H, Q, Q] × [H, Q, P]`` batched matmul
 back to the MXU.  The inter-chunk state scan stays in jnp (it is O(n_chunks)
 and bandwidth-trivial).
 
-Validated in ``interpret=True`` mode against ``ref.ssd_chunk_ref``.
+Validated in interpret mode on CPU against ``ref.ssd_chunk_ref``.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import pallas_on_platform
 
 
 def _ssd_kernel(xh_ref, bm_ref, cm_ref, dt_ref, cum_ref, out_ref):
@@ -59,16 +59,12 @@ def _ssd_kernel(xh_ref, bm_ref, cm_ref, dt_ref, cum_ref, out_ref):
     out_ref[0] = y.transpose(1, 0, 2).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk_pallas(xh, bm, cm, dt, cum, *, interpret: bool = True):
-    """Intra-chunk SSD.  ``xh [B, nc, Q, H, P]``, ``bm/cm [B, nc, Q, N]``,
-    ``dt/cum [B, nc, Q, H]`` → ``[B, nc, Q, H, P]`` (fp32)."""
-    b, nc, q, h, p = xh.shape
+def _ssd_chunk_call(xh, bm, cm, dt, cum, *, interpret):
+    bnc, q, h, p = xh.shape
     n = bm.shape[-1]
-    flat = lambda t: t.reshape(b * nc, *t.shape[2:])
-    out = pl.pallas_call(
+    return pl.pallas_call(
         _ssd_kernel,
-        grid=(b * nc,),
+        grid=(bnc,),
         in_specs=[
             pl.BlockSpec((1, q, h, p), lambda i: (i, 0, 0, 0)),
             pl.BlockSpec((1, q, n), lambda i: (i, 0, 0)),
@@ -77,7 +73,17 @@ def ssd_chunk_pallas(xh, bm, cm, dt, cum, *, interpret: bool = True):
             pl.BlockSpec((1, q, h), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, q, h, p), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * nc, q, h, p), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((bnc, q, h, p), jnp.float32),
         interpret=interpret,
-    )(flat(xh), flat(bm), flat(cm), flat(dt), flat(cum))
+    )(xh, bm, cm, dt, cum)
+
+
+@jax.jit
+def ssd_chunk_pallas(xh, bm, cm, dt, cum):
+    """Intra-chunk SSD.  ``xh [B, nc, Q, H, P]``, ``bm/cm [B, nc, Q, N]``,
+    ``dt/cum [B, nc, Q, H]`` → ``[B, nc, Q, H, P]`` (fp32)."""
+    b, nc, q, h, p = xh.shape
+    flat = lambda t: t.reshape(b * nc, *t.shape[2:])
+    out = pallas_on_platform(_ssd_chunk_call, flat(xh), flat(bm), flat(cm),
+                             flat(dt), flat(cum))
     return out.reshape(b, nc, q, h, p)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove every (architecture × input-shape × mesh) lowers
 and compiles, and extract the roofline terms from the compiled artifact.
 
@@ -8,9 +5,15 @@ Run:
     PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-8b --shape decode_32k
     PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod] [--out exp.json]
 
-The first two lines above MUST stay first: jax locks the device count on
-first init, and only the dry-run wants 512 placeholder CPU devices.
+Run as a script it asks for 512 placeholder CPU devices before JAX is
+imported (JAX fixes the device count at first init), unless the caller set
+``XLA_FLAGS`` already; importing the module leaves ``XLA_FLAGS`` alone.
 """
+
+import os
+
+if __name__ == "__main__":
+    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 import argparse
 import dataclasses

@@ -11,13 +11,10 @@ import jax
 
 
 def make_mesh_auto(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the installed jax has
-    them (>= 0.5); older jax has no ``jax.sharding.AxisType`` and Auto is
-    its only behavior, so omitting the argument is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """``jax.make_mesh`` with every axis of type Auto (GSPMD chooses the
+    collectives)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -163,35 +163,23 @@ def bidirectional_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.einsum("bhqk,bkhd->bqhd", w, vq)
 
 
-# Route decode attention through the Pallas flash-decode kernel
-# (repro.kernels.gather_attention).  interpret=True on CPU; on TPU flip
-# PALLAS_INTERPRET to False.  Toggled per-call-site via set_use_pallas.
-USE_PALLAS_DECODE = False
-PALLAS_INTERPRET = True
-
-
-def set_use_pallas(enabled: bool, *, interpret: bool = True) -> None:
-    global USE_PALLAS_DECODE, PALLAS_INTERPRET
-    USE_PALLAS_DECODE = enabled
-    PALLAS_INTERPRET = interpret
-
-
 def decode_attention(q: jax.Array, k_ctx: jax.Array, v_ctx: jax.Array,
-                     ctx_mask: jax.Array, k_new: jax.Array, v_new: jax.Array) -> jax.Array:
+                     ctx_mask: jax.Array, k_new: jax.Array, v_new: jax.Array,
+                     *, use_pallas: bool = False) -> jax.Array:
     """One-token decode over an assembled (masked) context plus self.
 
     q [B,H,d]; k_ctx/v_ctx [B,N,Hk,d]; ctx_mask [B,N]; k_new/v_new [B,Hk,d].
-    Returns [B,H,d].
+    Returns [B,H,d].  ``use_pallas`` routes through the flash-decode kernel
+    (repro.kernels.gather_attention).
     """
     b, h, d = q.shape
     hk = k_ctx.shape[2]
     k_all = jnp.concatenate([k_ctx, k_new[:, None]], axis=1)
     v_all = jnp.concatenate([v_ctx, v_new[:, None]], axis=1)
     mask = jnp.concatenate([ctx_mask, jnp.ones((b, 1), bool)], axis=1)
-    if USE_PALLAS_DECODE:
+    if use_pallas:
         from repro.kernels import ops
-        return ops.gather_attention(q, k_all, v_all, mask,
-                                    interpret=PALLAS_INTERPRET).astype(q.dtype)
+        return ops.gather_attention(q, k_all, v_all, mask).astype(q.dtype)
     kq = repeat_kv(k_all, h // hk)
     vq = repeat_kv(v_all, h // hk)
     scores = jnp.einsum("bhd,bnhd->bhn", q, kq) / jnp.sqrt(d).astype(q.dtype)

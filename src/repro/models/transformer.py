@@ -345,8 +345,9 @@ class TransformerAdapter:
         """
         return L.gather_slots(dev_k, dev_v, slots, tail_k, tail_v, tail_fill)
 
-    @functools.partial(jax.jit, static_argnames=("self", "layer"))
-    def decode_block(self, params, layer, x, positions, k_ctx, v_ctx, ctx_mask):
+    @functools.partial(jax.jit, static_argnames=("self", "layer", "use_pallas"))
+    def decode_block(self, params, layer, x, positions, k_ctx, v_ctx, ctx_mask,
+                     use_pallas=False):
         cfg = self.cfg
         kind = cfg.blocks[layer]
         blk = params["blocks"][layer]
@@ -357,7 +358,8 @@ class TransformerAdapter:
             n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
             rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm)
         q, k_new, v_new = q[:, 0], k_new[:, 0], v_new[:, 0]
-        o = L.decode_attention(q, k_ctx, v_ctx, ctx_mask, k_new, v_new)
+        o = L.decode_attention(q, k_ctx, v_ctx, ctx_mask, k_new, v_new,
+                               use_pallas=use_pallas)
         x = x + o.reshape(x.shape[0], cfg.n_heads * cfg.head_dim) @ attn_p["wo"]
         h2 = L.rmsnorm(mlp_holder["mlp_norm"], x)
         if kind == "moe_attn":

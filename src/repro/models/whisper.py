@@ -195,7 +195,8 @@ class WhisperAdapter:
             x = x + oc.reshape(*lead, -1) @ blk["cross"]["wo"]
         return x + L.gelu_mlp(blk["mlp"], L.layernorm(blk["ln_mlp"], x))
 
-    def decode_block(self, params, layer, x, positions, k_ctx, v_ctx, ctx_mask):
+    def decode_block(self, params, layer, x, positions, k_ctx, v_ctx, ctx_mask,
+                     use_pallas=False):
         cfg = self.cfg
         blk = params["dec_blocks"][layer]
         if layer == 0:
@@ -205,7 +206,8 @@ class WhisperAdapter:
         q = (h @ blk["attn"]["wq"]).reshape(b, cfg.n_heads, cfg.head_dim)
         k_new = (h @ blk["attn"]["wk"]).reshape(b, cfg.n_kv_heads, cfg.head_dim)
         v_new = (h @ blk["attn"]["wv"]).reshape(b, cfg.n_kv_heads, cfg.head_dim)
-        o = L.decode_attention(q, k_ctx, v_ctx, ctx_mask, k_new, v_new)
+        o = L.decode_attention(q, k_ctx, v_ctx, ctx_mask, k_new, v_new,
+                               use_pallas=use_pallas)
         x = x + o.reshape(b, -1) @ blk["attn"]["wo"]
         x = self._cross_and_mlp(blk, x, layer)
         return x, k_new, v_new

@@ -32,13 +32,8 @@ NEG = -1e30
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    try:  # jax >= 0.5
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def _local_partial(q, q_lr, k_lr, k, v, k_new, v_new, start, length,
