@@ -1,0 +1,37 @@
+"""Order statistics and the request timings built on them.
+
+``percentile`` interpolates linearly between closest ranks on the sorted
+sample (numpy's default ``method="linear"``), in plain Python.
+"""
+
+from __future__ import annotations
+
+from typing import Sequence
+
+
+def percentile(xs: Sequence[float], q: float) -> float:
+    """Linear-interpolated ``q``-th percentile (0..100); raises on no data."""
+    if not xs:
+        raise ValueError("percentile of empty sequence")
+    if not 0.0 <= q <= 100.0:
+        raise ValueError(f"q must be in [0, 100], got {q}")
+    s = sorted(float(x) for x in xs)
+    pos = (q / 100.0) * (len(s) - 1)
+    lo = int(pos)
+    hi = min(lo + 1, len(s) - 1)
+    frac = pos - lo
+    return s[lo] * (1.0 - frac) + s[hi] * frac
+
+
+def inter_token_gaps(requests, t_open: float, t_close: float) -> list[float]:
+    """Every gap between consecutive tokens of a request whose later token
+    came in ``[t_open, t_close]``."""
+    out = []
+    for r in requests:
+        ts = r.token_t
+        out.extend(b - a for a, b in zip(ts, ts[1:]) if t_open <= b <= t_close)
+    return out
+
+
+def tokens_in(requests, t_open: float, t_close: float) -> int:
+    return sum(1 for r in requests for t in r.token_t if t_open <= t <= t_close)
