@@ -195,7 +195,7 @@ class StepStats:
     io_bytes: int = 0                # cumulative disk bytes read since engine start
     io_requests: int = 0             # cumulative read requests since start
     warm_bytes: int = 0              # warm-tier-served bytes this step (disk units)
-    wall_seconds: float = 0.0        # measured wall time of this step
+    wall_seconds: float = 0.0        # host time to dispatch this step (logits not awaited)
     io_wait_seconds: float = 0.0     # measured wall time blocked on fetches
     h2d_bytes: int = 0               # host→device KV payload bytes this step
     active_rows: int = 0             # rows decoded this step (continuous batching)
@@ -371,7 +371,7 @@ class KVSwapEngine:
                 "kvswap_step_pipelined_seconds",
                 "modeled layer-pipelined decode-step latency")
             self._m_hist_wall = reg.histogram(
-                "kvswap_step_wall_seconds", "measured decode-step wall time")
+                "kvswap_step_wall_seconds", "host time to dispatch a decode step")
         if cfg.compute not in hardware.COMPUTES:
             raise ValueError(f"unknown compute {cfg.compute!r}; "
                              f"expected one of {sorted(hardware.COMPUTES)}")
@@ -1026,61 +1026,68 @@ class KVSwapEngine:
             raise RuntimeError("no active rows (prefill or admit_row first)")
         if (self.row_seq[active] + 1 > self.cap_tokens).any():
             raise RuntimeError("KV capacity exceeded; raise cfg.max_seq")
-        t0 = time.perf_counter()
-        if self.device_resident:
-            self._ensure_device_state()
-        warm_bytes0 = self.accountant.warm_bytes
-        self._h2d_step = 0
-        self._step_active = active
-        self.quality.begin_step()
-        b = self.batch
-        if n_active == b:
-            tok = jnp.asarray(token_ids).reshape(b, 1)   # stays on device
-        else:
-            tok = jnp.asarray(
-                np.where(active, np.asarray(token_ids).reshape(b), 0)
-            ).reshape(b, 1)
-        pos = jnp.asarray(self.row_seq.astype(np.int32))
-        x = self.model.embed(self.params, tok)[:, 0]
-        valid = jnp.asarray(self.row_valid.astype(np.int32))
+        obs = self.obs
+        with obs.phase("decode_step", step=len(self.step_log)):
+            t0 = time.perf_counter()
+            with obs.phase("step_prologue"):
+                if self.device_resident:
+                    self._ensure_device_state()
+                warm_bytes0 = self.accountant.warm_bytes
+                self._h2d_step = 0
+                self._step_active = active
+                self.quality.begin_step()
+                b = self.batch
+                if n_active == b:
+                    tok = jnp.asarray(token_ids).reshape(b, 1)   # stays on device
+                else:
+                    tok = jnp.asarray(
+                        np.where(active, np.asarray(token_ids).reshape(b), 0)
+                    ).reshape(b, 1)
+                pos = jnp.asarray(self.row_seq.astype(np.int32))
+                x = self.model.embed(self.params, tok)[:, 0]
+                valid = jnp.asarray(self.row_valid.astype(np.int32))
 
-        t_compute: list[float] = []
-        t_io: list[float] = []
-        flush_rows: list[tuple[int, int, jax.Array]] = []   # (layer, row, k_lr rows)
-        if self.prefetcher is not None:
-            x, io_wait = self._layers_async(x, pos, valid, t_compute, t_io, flush_rows)
-        else:
-            x, io_wait = self._layers_sync(x, pos, valid, t_compute, t_io, flush_rows)
+            t_compute: list[float] = []
+            t_io: list[float] = []
+            flush_rows: list[tuple[int, int, jax.Array]] = []   # (layer, row, k_lr rows)
+            if self.prefetcher is not None:
+                x, io_wait = self._layers_async(x, pos, valid, t_compute, t_io, flush_rows)
+            else:
+                x, io_wait = self._layers_sync(x, pos, valid, t_compute, t_io, flush_rows)
 
-        for layer, bi, rows in flush_rows:
-            self.k_lr[layer] = _klr_append_row(
-                self.k_lr[layer], rows, jnp.int32(bi), jnp.int32(self.row_valid[bi]))
-        for bi in {bi for _, bi, _ in flush_rows}:
-            self.row_valid[bi] += self.cfg.group_size
-        self.row_seq[active] += 1
+            with obs.phase("klr_append"):
+                for layer, bi, rows in flush_rows:
+                    self.k_lr[layer] = _klr_append_row(
+                        self.k_lr[layer], rows, jnp.int32(bi),
+                        jnp.int32(self.row_valid[bi]))
+                for bi in {bi for _, bi, _ in flush_rows}:
+                    self.row_valid[bi] += self.cfg.group_size
+                self.row_seq[active] += 1
 
-        stats = StepStats()
-        stats.io_seconds = sum(t_io)
-        stats.compute_seconds = sum(t_compute)
-        stats.pipelined_seconds = self._pipeline_latency(t_compute, t_io)
-        snap = self.accountant.snapshot()
-        stats.io_bytes = snap["read_bytes"]
-        stats.io_requests = snap["read_requests"]
-        stats.warm_bytes = snap["warm_bytes"] - warm_bytes0
-        stats.io_wait_seconds = io_wait
-        stats.h2d_bytes = self._h2d_step
-        stats.active_rows = n_active
-        qc = self.quality.finish_step()
-        stats.pred_shared_groups = qc.shared_groups
-        stats.pred_prev_groups = qc.prev_groups
-        stats.pred_cur_groups = qc.cur_groups
-        stats.stale_groups = qc.stale_groups
-        stats.resident_groups = qc.resident_groups
-        stats.wall_seconds = time.perf_counter() - t0
-        self.step_log.append(stats)
-        if self.obs.enabled:
-            self._obs_step(stats, t_compute, t_io)
-        return self.model.logits(self.params, x)
+            stats = StepStats()
+            stats.io_seconds = sum(t_io)
+            stats.compute_seconds = sum(t_compute)
+            stats.pipelined_seconds = self._pipeline_latency(t_compute, t_io)
+            snap = self.accountant.snapshot()
+            stats.io_bytes = snap["read_bytes"]
+            stats.io_requests = snap["read_requests"]
+            stats.warm_bytes = snap["warm_bytes"] - warm_bytes0
+            stats.io_wait_seconds = io_wait
+            stats.h2d_bytes = self._h2d_step
+            stats.active_rows = n_active
+            qc = self.quality.finish_step()
+            stats.pred_shared_groups = qc.shared_groups
+            stats.pred_prev_groups = qc.prev_groups
+            stats.pred_cur_groups = qc.cur_groups
+            stats.stale_groups = qc.stale_groups
+            stats.resident_groups = qc.resident_groups
+            logits = self.model.logits(self.params, x)
+            # host time to dispatch the step: the logits are not waited for
+            stats.wall_seconds = time.perf_counter() - t0
+            self.step_log.append(stats)
+            if obs.enabled:
+                self._obs_step(stats, t_compute, t_io)
+        return logits
 
     def _obs_step(self, stats: StepStats, t_compute: Sequence[float],
                   t_io: Sequence[float]) -> None:
@@ -1163,19 +1170,17 @@ class KVSwapEngine:
         here, just before the fetch needs it.  Inactive rows are masked out
         on host — they select no groups, so the fetch issues no disk reads
         for them (the active-row contract of continuous batching)."""
-        obs = self.obs
-        if obs.enabled:
-            p0 = obs.tracer.now_wall()
-        q_pred = self.model.predict_query(self.params, layer, pred_src, pos)
-        ids, mask = jax.device_get(self._predict(j, q_pred, valid))
-        masked = mask & self._step_active[:, None]
-        # score the selection for prefetch quality (main thread in both
-        # modes: async predicts before submitting the fetch, so layer j's
-        # reuse buffer is quiescent here)
-        self.quality.observe(layer, ids, masked, self.reuse[j])
-        if obs.enabled:
-            obs.tracer.add(f"predict L{layer}", f"layer{layer}", cat="predict",
-                           wall_t0=p0, wall_dur=obs.tracer.now_wall() - p0)
+        with self.obs.phase("predict", layer=layer):
+            q_pred = self.model.predict_query(self.params, layer, pred_src, pos)
+            selected = self._predict(j, q_pred, valid)
+            # the host blocks here until the device has selected
+            with self.obs.phase("select_pull", layer=layer):
+                ids, mask = jax.device_get(selected)
+            masked = mask & self._step_active[:, None]
+            # score the selection for prefetch quality (main thread in both
+            # modes: async predicts before submitting the fetch, so layer j's
+            # reuse buffer is quiescent here)
+            self.quality.observe(layer, ids, masked, self.reuse[j])
         return ids, masked
 
     def _state_layer(self, layer: int, x: jax.Array, pos: jax.Array,
@@ -1191,43 +1196,37 @@ class KVSwapEngine:
 
     def _kv_layer(self, layer: int, j: int, x: jax.Array, pos: jax.Array, table,
                   t_compute: list[float], flush_rows: list) -> jax.Array:
-        obs = self.obs
-        if obs.enabled:
-            a0 = obs.tracer.now_wall()
         if self.device_resident:
-            x = self._kv_layer_device(layer, j, x, pos, table, t_compute,
-                                      flush_rows)
-        else:
-            x = self._kv_layer_host(layer, j, x, pos, table, t_compute,
-                                    flush_rows)
-        if obs.enabled:
-            obs.tracer.add(f"attn L{layer}", f"layer{layer}", cat="attn",
-                           wall_t0=a0, wall_dur=obs.tracer.now_wall() - a0,
-                           args={"modeled_compute_s": t_compute[-1]})
-        return x
+            return self._kv_layer_device(layer, j, x, pos, table, t_compute,
+                                         flush_rows)
+        return self._kv_layer_host(layer, j, x, pos, table, t_compute,
+                                   flush_rows)
 
     def _kv_layer_host(self, layer: int, j: int, x: jax.Array, pos: jax.Array,
                        table, t_compute: list[float], flush_rows: list) -> jax.Array:
         """Seed behavior (the A/B control): host concat + full upload."""
         cfg = self.cfg
-        k_ctx, v_ctx, tok_mask, _ = self.managers[j].gather(table)
-        self._h2d_step += k_ctx.nbytes + v_ctx.nbytes
-        x, k_new, v_new = self.model.decode_block(
-            self.params, layer, x, pos,
-            jnp.asarray(k_ctx), jnp.asarray(v_ctx), jnp.asarray(tok_mask),
-            use_pallas=cfg.use_pallas,
-        )
-        completed = self.managers[j].append_token_rows(
-            np.asarray(jax.device_get(k_new), dtype=cfg.np_dtype),
-            np.asarray(jax.device_get(v_new), dtype=cfg.np_dtype),
-            self._step_active,
-        )
-        for bi, k_g, _ in completed:
-            # compress the completed group's keys exactly as stored on disk
-            k_gj = jnp.asarray(k_g[None], dtype=jnp.float32)
-            self._h2d_step += k_gj.nbytes
-            flush_rows.append((j, bi, compress_k(k_gj, self.adapter)))
-        self._charge_layer_compute(j, k_ctx.shape[1] + 1, t_compute)
+        with self.obs.phase("attn", layer=layer) as ph:
+            k_ctx, v_ctx, tok_mask, _ = self.managers[j].gather(table)
+            self._h2d_step += k_ctx.nbytes + v_ctx.nbytes
+            x, k_new, v_new = self.model.decode_block(
+                self.params, layer, x, pos,
+                jnp.asarray(k_ctx), jnp.asarray(v_ctx), jnp.asarray(tok_mask),
+                use_pallas=cfg.use_pallas,
+            )
+            self._charge_layer_compute(j, k_ctx.shape[1] + 1, t_compute)
+            ph.note(modeled_compute_s=t_compute[-1])
+        with self.obs.phase("spill", layer=layer):
+            completed = self.managers[j].append_token_rows(
+                np.asarray(jax.device_get(k_new), dtype=cfg.np_dtype),
+                np.asarray(jax.device_get(v_new), dtype=cfg.np_dtype),
+                self._step_active,
+            )
+            for bi, k_g, _ in completed:
+                # compress the completed group's keys exactly as stored on disk
+                k_gj = jnp.asarray(k_g[None], dtype=jnp.float32)
+                self._h2d_step += k_gj.nbytes
+                flush_rows.append((j, bi, compress_k(k_gj, self.adapter)))
         return x
 
     def _kv_layer_device(self, layer: int, j: int, x: jax.Array, pos: jax.Array,
@@ -1245,55 +1244,63 @@ class KVSwapEngine:
         cfg = self.cfg
         g = cfg.group_size
         mgr = self.managers[j]
-        self._h2d_step += mgr.sync_device(table)
-        mirror = self.reuse[j].device
-        k_ctx, v_ctx, tok_mask = self.model.gather_context(
-            mirror.k, mirror.v, jnp.asarray(table.slots),
-            self._tail_k[j], self._tail_v[j],
-            jnp.asarray(table.rolling_fill.astype(np.int32)))
-        # overflow groups that couldn't enter the pinned-full reuse buffer
-        # (slots == -2) are staged on host: upload transiently and overwrite
-        # their gathered rows (rare — C smaller than the step's working set).
-        # All staged rows go in ONE batched update so the context is copied
-        # once, not once per staged group.
-        if table.staged:
-            rows_b: list[int] = []
-            rows_t: list[int] = []
-            pay_k: list[np.ndarray] = []
-            pay_v: list[np.ndarray] = []
-            for (bi, gid), kv in table.staged.items():
-                self._h2d_step += kv.nbytes
-                for mi in np.nonzero((table.group_ids[bi] == gid)
-                                     & (table.slots[bi] == -2))[0]:
-                    rows_b.extend([bi] * g)
-                    rows_t.extend(range(int(mi) * g, (int(mi) + 1) * g))
-                    pay_k.append(kv[:, 0])
-                    pay_v.append(kv[:, 1])
-            if rows_b:
-                bb = jnp.asarray(np.asarray(rows_b))
-                tt = jnp.asarray(np.asarray(rows_t))
-                k_ctx = k_ctx.at[bb, tt].set(jnp.asarray(np.concatenate(pay_k)))
-                v_ctx = v_ctx.at[bb, tt].set(jnp.asarray(np.concatenate(pay_v)))
-        x, k_new, v_new = self.model.decode_block(
-            self.params, layer, x, pos, k_ctx, v_ctx, tok_mask,
-            use_pallas=cfg.use_pallas)
-        # scatter each active row's fresh token into its own tail position
-        # (rows sit at different fills under continuous batching)
-        act = jnp.asarray(self._step_active)
-        fidx = jnp.asarray(mgr.rolling.fills.astype(np.int32))
-        self._tail_k[j] = _tail_write(self._tail_k[j], k_new, fidx, act)
-        self._tail_v[j] = _tail_write(self._tail_v[j], v_new, fidx, act)
-        for bi in mgr.rolling.advance_rows(self._step_active):
-            # row's group complete: cast exactly as the host path stores it;
-            # one download feeds the disk spill, the k_lr append compresses
-            # straight from the device copy
-            grp_k = self._tail_k[j][bi].astype(cfg.np_dtype)
-            grp_v = self._tail_v[j][bi].astype(cfg.np_dtype)
-            k_np, v_np = (np.asarray(a) for a in jax.device_get((grp_k, grp_v)))
-            mgr.spill_group_row(bi, k_np, v_np)
-            flush_rows.append(
-                (j, bi, compress_k(grp_k[None].astype(jnp.float32), self.adapter)))
-        self._charge_layer_compute(j, k_ctx.shape[1] + 1, t_compute)
+        with self.obs.phase("mirror_upload", layer=layer):
+            self._h2d_step += mgr.sync_device(table)
+        with self.obs.phase("attn", layer=layer) as ph:
+            mirror = self.reuse[j].device
+            k_ctx, v_ctx, tok_mask = self.model.gather_context(
+                mirror.k, mirror.v, jnp.asarray(table.slots),
+                self._tail_k[j], self._tail_v[j],
+                jnp.asarray(table.rolling_fill.astype(np.int32)))
+            # overflow groups that couldn't enter the pinned-full reuse buffer
+            # (slots == -2) are staged on host: upload transiently and overwrite
+            # their gathered rows (rare — C smaller than the step's working set).
+            # All staged rows go in ONE batched update so the context is copied
+            # once, not once per staged group.
+            if table.staged:
+                rows_b: list[int] = []
+                rows_t: list[int] = []
+                pay_k: list[np.ndarray] = []
+                pay_v: list[np.ndarray] = []
+                for (bi, gid), kv in table.staged.items():
+                    self._h2d_step += kv.nbytes
+                    for mi in np.nonzero((table.group_ids[bi] == gid)
+                                         & (table.slots[bi] == -2))[0]:
+                        rows_b.extend([bi] * g)
+                        rows_t.extend(range(int(mi) * g, (int(mi) + 1) * g))
+                        pay_k.append(kv[:, 0])
+                        pay_v.append(kv[:, 1])
+                if rows_b:
+                    bb = jnp.asarray(np.asarray(rows_b))
+                    tt = jnp.asarray(np.asarray(rows_t))
+                    k_ctx = k_ctx.at[bb, tt].set(jnp.asarray(np.concatenate(pay_k)))
+                    v_ctx = v_ctx.at[bb, tt].set(jnp.asarray(np.concatenate(pay_v)))
+            x, k_new, v_new = self.model.decode_block(
+                self.params, layer, x, pos, k_ctx, v_ctx, tok_mask,
+                use_pallas=cfg.use_pallas)
+            # scatter each active row's fresh token into its own tail position
+            # (rows sit at different fills under continuous batching)
+            act = jnp.asarray(self._step_active)
+            fidx = jnp.asarray(mgr.rolling.fills.astype(np.int32))
+            self._tail_k[j] = _tail_write(self._tail_k[j], k_new, fidx, act)
+            self._tail_v[j] = _tail_write(self._tail_v[j], v_new, fidx, act)
+            completed = mgr.rolling.advance_rows(self._step_active)
+            self._charge_layer_compute(j, k_ctx.shape[1] + 1, t_compute)
+            ph.note(modeled_compute_s=t_compute[-1])
+        if completed:
+            with self.obs.phase("spill", layer=layer):
+                for bi in completed:
+                    # row's group complete: cast exactly as the host path
+                    # stores it; one download feeds the disk spill, the k_lr
+                    # append compresses straight from the device copy
+                    grp_k = self._tail_k[j][bi].astype(cfg.np_dtype)
+                    grp_v = self._tail_v[j][bi].astype(cfg.np_dtype)
+                    k_np, v_np = (np.asarray(a)
+                                  for a in jax.device_get((grp_k, grp_v)))
+                    mgr.spill_group_row(bi, k_np, v_np)
+                    flush_rows.append(
+                        (j, bi, compress_k(grp_k[None].astype(jnp.float32),
+                                           self.adapter)))
         return x
 
     def _charge_layer_compute(self, j: int, n_ctx: int,
@@ -1321,20 +1328,15 @@ class KVSwapEngine:
             j = self._kv_index[layer]
             pred_src = x if (self.cfg.predict_from == "self" or layer == 0) else x_prev
             ids, mask = self._predict_for(layer, j, pred_src, pos, valid)
-            w0 = time.perf_counter()
-            with self.accountant.track() as tr:
-                table = self.managers[j].fetch(ids, mask)
-            dt = time.perf_counter() - w0
-            io_wait += dt
-            # the fetch-serve lane: disk reads plus warm-tier memcpy+dequant
-            t_io.append(tr.read_seconds + tr.warm_seconds)
-            if self.obs.enabled:
-                self.obs.tracer.add(
-                    f"fetch L{layer}", f"layer{layer}", cat="fetch",
-                    wall_t0=self.obs.tracer.now_wall() - dt, wall_dur=dt,
-                    args={"modeled_io_s": tr.read_seconds + tr.warm_seconds,
-                          "read_bytes": tr.read_bytes,
-                          "warm_bytes": tr.warm_bytes})
+            with self.obs.phase("fetch", layer=layer) as ph:
+                w0 = time.perf_counter()
+                with self.accountant.track() as tr:
+                    table = self.managers[j].fetch(ids, mask)
+                io_wait += time.perf_counter() - w0
+                # the fetch-serve lane: disk reads plus warm-tier memcpy+dequant
+                t_io.append(tr.read_seconds + tr.warm_seconds)
+                ph.note(modeled_io_s=t_io[-1], read_bytes=tr.read_bytes,
+                        warm_bytes=tr.warm_bytes)
             x_prev = x
             x = self._kv_layer(layer, j, x, pos, table, t_compute, flush_rows)
         return x, io_wait
@@ -1369,18 +1371,14 @@ class KVSwapEngine:
                     t_io.append(0.0)
                     continue
                 j = self._kv_index[layer]
-                w0 = time.perf_counter()
-                res = buf.take(j)
-                dt = time.perf_counter() - w0
-                io_wait += dt
-                t_io.append(res.io_seconds)
-                if self.obs.enabled:
-                    # the wall time *exposed* by this layer's fetch — the
-                    # worker records the fetch itself on its own lane
-                    self.obs.tracer.add(
-                        f"wait L{layer}", f"layer{layer}", cat="fetch",
-                        wall_t0=self.obs.tracer.now_wall() - dt, wall_dur=dt,
-                        args={"modeled_io_s": res.io_seconds})
+                # the wall time *exposed* by this layer's fetch — the
+                # worker records the fetch itself on its own lane
+                with self.obs.phase("fetch_wait", layer=layer) as ph:
+                    w0 = time.perf_counter()
+                    res = buf.take(j)
+                    io_wait += time.perf_counter() - w0
+                    t_io.append(res.io_seconds)
+                    ph.note(modeled_io_s=res.io_seconds)
                 x = self._kv_layer(layer, j, x, pos, res.table, t_compute, flush_rows)
         except BaseException:
             buf.drain()   # never leave staged futures behind on an error

@@ -31,6 +31,8 @@ import time
 from concurrent.futures import Future
 from typing import Callable
 
+from repro.obs import NULL_OBS
+
 __all__ = [
     "DoubleBuffer",
     "PrefetchQueueFull",
@@ -104,10 +106,11 @@ class PrefetchWorker:
             raise ValueError("max_pending must be >= 1")
         self._fetch_fn = fetch_fn
         self._accountant = accountant
-        # observability: each worker thread records its serviced fetches as
-        # wall spans on its own lane (the thread's name), which is where the
-        # measured overlap — worker lanes busy under the engine lane — shows
-        self._obs = obs
+        # observability: each serviced fetch is a kvswap.prefetch profiler
+        # span on its worker thread and, with an enabled handle, a wall span
+        # on the thread's own lane, which is where the measured overlap —
+        # worker lanes busy under the engine lane — shows
+        self._obs = obs if obs is not None else NULL_OBS
         self.max_pending = max_pending
         self._cv = threading.Condition()
         self._pending: dict[int, collections.deque] = {}
@@ -198,29 +201,21 @@ class PrefetchWorker:
         try:
             if not req.future.set_running_or_notify_cancel():
                 return  # consumer cancelled while queued
-            t0 = time.perf_counter()
-            if self._accountant is not None:
-                with self._accountant.track() as tr:
+            with self._obs.phase("prefetch", layer=req.layer) as ph:
+                t0 = time.perf_counter()
+                if self._accountant is not None:
+                    with self._accountant.track() as tr:
+                        table = self._fetch_fn(req.layer, *req.args)
+                    res = PrefetchResult(
+                        table=table,
+                        io_seconds=tr.read_seconds + tr.warm_seconds,
+                        io_bytes=tr.read_bytes, io_requests=tr.read_requests,
+                        wall_seconds=time.perf_counter() - t0)
+                else:
                     table = self._fetch_fn(req.layer, *req.args)
-                res = PrefetchResult(
-                    table=table,
-                    io_seconds=tr.read_seconds + tr.warm_seconds,
-                    io_bytes=tr.read_bytes, io_requests=tr.read_requests,
-                    wall_seconds=time.perf_counter() - t0)
-            else:
-                table = self._fetch_fn(req.layer, *req.args)
-                res = PrefetchResult(
-                    table=table, wall_seconds=time.perf_counter() - t0)
-            obs = self._obs
-            if obs is not None and obs.enabled:
-                obs.tracer.add(
-                    f"fetch L{req.layer}",
-                    threading.current_thread().name, cat="prefetch",
-                    wall_t0=obs.tracer.now_wall() - res.wall_seconds,
-                    wall_dur=res.wall_seconds,
-                    args={"layer": req.layer,
-                          "modeled_io_s": res.io_seconds,
-                          "read_bytes": res.io_bytes})
+                    res = PrefetchResult(
+                        table=table, wall_seconds=time.perf_counter() - t0)
+                ph.note(modeled_io_s=res.io_seconds, read_bytes=res.io_bytes)
             req.future.set_result(res)
             ok = True
         except BaseException as exc:  # propagate to the consumer

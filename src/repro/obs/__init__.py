@@ -26,18 +26,28 @@ ServeSession`), never inside :class:`~repro.core.engine.EngineConfig` —
 the config is a frozen, ``dataclasses.asdict``-serialized value object and
 must stay one.
 
+Profiler spans: :meth:`Observability.phase` names each phase of the decode
+step, the admission and the prefetch worker as a ``kvswap.*``
+``jax.profiler.TraceAnnotation``, with or without an enabled handle, so a
+profiler trace names what the host did in every device gap
+(docs/observability.md, "Profiler spans").
+
 Disabled-path contract: with no ``obs`` handle (or ``enabled=False``)
-every instrumentation site reduces to one attribute load + bool test, no
-allocation, no lock — and the token streams are bit-identical to an
-uninstrumented engine (``tests/test_obs.py`` pins both properties).
+every instrumentation site reduces to one attribute load + bool test, or
+to a profiler annotation that records nothing without a profiler session:
+no span, no lock, no registry write — and the token streams are
+bit-identical to an uninstrumented engine (``tests/test_obs.py`` pins both
+properties).
 """
 
 from __future__ import annotations
 
+import threading
+
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.quality import PrefetchQualityMeter, QualityCounts
-from repro.obs.span import (MODEL_PID, WALL_PID, Span, SpanTracer,
-                            validate_trace_events)
+from repro.obs.span import (MODEL_PID, WALL_PID, Phase, Span, SpanTracer,
+                            WallPhase, validate_trace_events)
 
 __all__ = [
     "Counter",
@@ -47,6 +57,7 @@ __all__ = [
     "MODEL_PID",
     "NULL_OBS",
     "Observability",
+    "Phase",
     "PrefetchQualityMeter",
     "QualityCounts",
     "Span",
@@ -54,6 +65,20 @@ __all__ = [
     "WALL_PID",
     "validate_trace_events",
 ]
+
+
+# phase -> (wall-span prefix, category) of the phases the tracer records,
+# as "<prefix> L{layer}" on the "layer{layer}" lane (the prefetch worker's
+# on its thread's lane); every other phase is on the profiler's clock only
+_WALL_SPANS = {
+    "predict": ("predict", "predict"),
+    "fetch": ("fetch", "fetch"),
+    "fetch_wait": ("wait", "fetch"),
+    "mirror_upload": ("upload", "attn"),
+    "attn": ("attn", "attn"),
+    "spill": ("spill", "attn"),
+    "prefetch": ("fetch", "prefetch"),
+}
 
 
 class Observability:
@@ -93,6 +118,22 @@ class Observability:
         if t > self.model_time:
             self.model_time = t
 
+    def phase(self, name: str, *, layer: int | None = None, **args) -> Phase:
+        """``with obs.phase("fetch", layer=i):`` names the body
+        ``kvswap.fetch`` on the profiler's clock (arguments ``layer`` and
+        ``args``), handle or not; an enabled handle also records the body
+        as a wall span on the tracer (see ``_WALL_SPANS``)."""
+        if layer is not None:
+            args["layer"] = layer
+        wall = _WALL_SPANS.get(name) if self.enabled else None
+        if wall is None:
+            return Phase(f"kvswap.{name}", **args)
+        prefix, cat = wall
+        track = (threading.current_thread().name if name == "prefetch"
+                 else f"layer{layer}")
+        return WallPhase(f"kvswap.{name}", args, self.tracer.wall_span(
+            f"{prefix} L{layer}", track, cat=cat, args=args))
+
     def export_trace(self, path) -> dict:
         return self.tracer.export(path)
 
@@ -102,6 +143,6 @@ class Observability:
 
 NULL_OBS = Observability(enabled=False)
 """Shared disabled handle — the default for every instrumented component.
-Never written to (all call sites guard on ``enabled``), so sharing one
-instance across engines is safe and keeps the disabled path allocation-free.
+Never written to (call sites guard on ``enabled``, and its :meth:`~Observability.
+phase` records no span), so sharing one instance across engines is safe.
 """

@@ -30,8 +30,10 @@ import json
 import threading
 import time
 
-__all__ = ["Span", "SpanTracer", "WALL_PID", "MODEL_PID",
-           "validate_trace_events"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Phase", "Span", "SpanTracer", "WallPhase", "WALL_PID",
+           "MODEL_PID", "validate_trace_events"]
 
 WALL_PID = 1
 MODEL_PID = 2
@@ -173,6 +175,41 @@ class _WallScope:
                          wall_t0=self._t0,
                          wall_dur=self._tracer.now_wall() - self._t0,
                          args=self.args or None)
+
+
+class Phase(TraceAnnotation):
+    """One phase of the served path on the profiler's clock: a
+    ``jax.profiler.TraceAnnotation``, which records nothing unless a
+    profiler session is active.  Arguments known only at exit go in
+    through :meth:`note`; they reach a :class:`WallPhase`'s span only, so
+    here the call does nothing."""
+
+    __slots__ = ()
+
+    def note(self, **args) -> None:
+        pass
+
+
+class WallPhase(Phase):
+    """A :class:`Phase` that also times its body with ``scope`` (a
+    :meth:`SpanTracer.wall_span`), whose span takes the arguments given
+    to :meth:`note`."""
+
+    def __init__(self, name: str, args: dict, scope: "_WallScope"):
+        super().__init__(name, **args)
+        self._scope = scope
+
+    def note(self, **args) -> None:
+        self._scope.args.update(args)
+
+    def __enter__(self):
+        super().__enter__()
+        self._scope.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._scope.__exit__(*exc)
+        return super().__exit__(*exc)
 
 
 def validate_trace_events(obj) -> dict:

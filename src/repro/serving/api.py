@@ -289,30 +289,31 @@ RequestRejected` (a ``ValueError``) and count on
             due = next((r for r in self._waiting if r.arrival <= self.now), None)
             if due is None:
                 break
-            # dequeue only after the admission succeeds, so an admission
-            # failure leaves the request visible instead of losing it
-            try:
-                logits = self.engine.admit_row(i, due.prompt, self.prefix_cache)
-            except StorageFault as exc:
-                # admit_row rolled the slot back (failure atomicity), so the
-                # slot is reusable; the request fails terminally — storage
-                # faults are not the submitter's doing, so this is a FAILED
-                # outcome, not a rejection
+            with self.obs.phase("admit", rid=due.rid):
+                # dequeue only after the admission succeeds, so an admission
+                # failure leaves the request visible instead of losing it
+                try:
+                    logits = self.engine.admit_row(i, due.prompt, self.prefix_cache)
+                except StorageFault as exc:
+                    # admit_row rolled the slot back (failure atomicity), so
+                    # the slot is reusable; the request fails terminally —
+                    # storage faults are not the submitter's doing, so this
+                    # is a FAILED outcome, not a rejection
+                    self._waiting.remove(due)
+                    due.output = np.asarray([], np.int64)
+                    self._terminal_failure(due, i, exc, events)
+                    continue
                 self._waiting.remove(due)
-                due.output = np.asarray([], np.int64)
-                self._terminal_failure(due, i, exc, events)
-                continue
-            self._waiting.remove(due)
-            rep = self.engine.prefill_report
-            self.now += rep["modeled_seconds"]
-            due.state, due.slot, due.admitted_at = RUNNING, i, self.now
-            due.cached_tokens = rep["cached_tokens"]
-            due.restored_tokens = rep["cached_tokens"]
-            sampler = due.sampler or make_row_sampler(due.sampling)
-            self._slots[i] = _Slot(due, sampler,
-                                   np.asarray(logits)[None, :])
-            events.append({"type": "admit", "rid": due.rid, "slot": i,
-                           "t": self.now, "cached_tokens": due.cached_tokens})
+                rep = self.engine.prefill_report
+                self.now += rep["modeled_seconds"]
+                due.state, due.slot, due.admitted_at = RUNNING, i, self.now
+                due.cached_tokens = rep["cached_tokens"]
+                due.restored_tokens = rep["cached_tokens"]
+                sampler = due.sampler or make_row_sampler(due.sampling)
+                self._slots[i] = _Slot(due, sampler,
+                                       np.asarray(logits)[None, :])
+                events.append({"type": "admit", "rid": due.rid, "slot": i,
+                               "t": self.now, "cached_tokens": due.cached_tokens})
 
     def _finish(self, i: int, events: list) -> None:
         slot = self._slots[i]
@@ -520,21 +521,22 @@ RequestRejected` (a ``ValueError``) and count on
         if not self._active():
             return events
         toks = np.zeros(self.n_slots, dtype=np.int64)
-        for i in self._active():
-            slot = self._slots[i]
-            tok = int(np.asarray(slot.sampler(slot.logits)).reshape(-1)[0])
-            slot.out.append(tok)
-            if len(slot.out) == 1:
-                slot.req.first_token_at = self.now
-            events.append({"type": "token", "rid": slot.req.rid, "slot": i,
-                           "token": tok})
-            if tok in slot.stop_set:
-                slot.req.stopped_early = True
-                self._finish(i, events)
-            elif len(slot.out) >= slot.req.max_new:
-                self._finish(i, events)
-            else:
-                toks[i] = tok
+        with self.obs.phase("sample"):
+            for i in self._active():
+                slot = self._slots[i]
+                tok = int(np.asarray(slot.sampler(slot.logits)).reshape(-1)[0])
+                slot.out.append(tok)
+                if len(slot.out) == 1:
+                    slot.req.first_token_at = self.now
+                events.append({"type": "token", "rid": slot.req.rid, "slot": i,
+                               "token": tok})
+                if tok in slot.stop_set:
+                    slot.req.stopped_early = True
+                    self._finish(i, events)
+                elif len(slot.out) >= slot.req.max_new:
+                    self._finish(i, events)
+                else:
+                    toks[i] = tok
         # slots freed above are refilled at the NEXT step's admission phase:
         # a request admitted now would join this decode step without having
         # sampled its first token (its logits come from the admission
@@ -542,7 +544,10 @@ RequestRejected` (a ``ValueError``) and count on
         active = self._active()
         if active:
             try:
-                logits = np.asarray(self.engine.decode_step(toks))
+                logits = self.engine.decode_step(toks)
+                # the logits' trip to the host: waits for the step's programs
+                with self.obs.phase("logits_pull"):
+                    logits = np.asarray(logits)
             except StorageFault as exc:
                 # unrecoverable mid-step fault: fail the culprit request,
                 # replay the rest (docs/robustness.md rung 2) — the session

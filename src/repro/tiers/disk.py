@@ -28,6 +28,7 @@ import numpy as np
 from repro.faults.errors import FetchFailed, StorageFault
 from repro.faults.retry import RetryPolicy, call_with_retries
 from repro.io.scheduler import ReadRun, ReadScheduler
+from repro.obs import NULL_OBS
 from repro.tiers.base import KVTier
 
 __all__ = ["DiskTier"]
@@ -54,8 +55,8 @@ class DiskTier(KVTier):
         self.retry = retry
         self.retries = 0          # retried attempts, lifetime
         self.fetch_failures = 0   # runs given up on, lifetime
-        self._obs = obs
-        if obs is not None and obs.enabled:
+        self._obs = obs if obs is not None else NULL_OBS
+        if self._obs.enabled:
             reg = obs.registry
             self._m_plan_requests = reg.counter(
                 "kvswap_read_plan_requests_total",
@@ -96,7 +97,7 @@ class DiskTier(KVTier):
 
             def backoff(delay: float) -> None:
                 self.retries += 1
-                if self._obs is not None and self._obs.enabled:
+                if self._obs.enabled:
                     self._m_retries.inc()
                 if acc is not None:
                     acc.charge_stall(delay)
@@ -105,7 +106,7 @@ class DiskTier(KVTier):
                                      on_backoff=backoff)
         except (StorageFault, OSError) as exc:
             self.fetch_failures += 1
-            if self._obs is not None and self._obs.enabled:
+            if self._obs.enabled:
                 self._m_fetch_failures.inc()
             raise FetchFailed(
                 f"layer {lyr} row {batch_idx} groups "
@@ -134,19 +135,20 @@ class DiskTier(KVTier):
         an engine tracks, so the residue is always empty — a group the
         store cannot read escalates as :class:`FetchFailed` rather than
         passing silently to a tier that does not exist."""
-        plan = self.scheduler.plan(gids)
-        if plan and self._obs is not None and self._obs.enabled:
-            st = self.scheduler.stats(plan)
-            self._m_plan_requests.inc(st["requests"])
-            self._m_plan_groups.inc(st["groups_read"])
-            self._m_plan_wasted.inc(st["groups_wasted"])
-        served: list[tuple[int, np.ndarray]] = []
-        for run in plan:
-            k_r, v_r = self.read_run_with_retry(row, run, layer=layer)
-            for gid in run.ids:
-                off = gid - run.start
-                served.append(
-                    (int(gid), np.stack([k_r[off], v_r[off]], axis=1)))
+        with self._obs.phase("disk_read", groups=len(gids)):
+            plan = self.scheduler.plan(gids)
+            if plan and self._obs.enabled:
+                st = self.scheduler.stats(plan)
+                self._m_plan_requests.inc(st["requests"])
+                self._m_plan_groups.inc(st["groups_read"])
+                self._m_plan_wasted.inc(st["groups_wasted"])
+            served: list[tuple[int, np.ndarray]] = []
+            for run in plan:
+                k_r, v_r = self.read_run_with_retry(row, run, layer=layer)
+                for gid in run.ids:
+                    off = gid - run.start
+                    served.append(
+                        (int(gid), np.stack([k_r[off], v_r[off]], axis=1)))
         return served, []
 
     def admit(self, layer: int, row: int, gid: int, kv: np.ndarray, *,

@@ -12,12 +12,17 @@ The contracts pinned here:
   vs off across ``device_resident`` × ``async_io``, zero spans / empty
   registry without a handle, and near-zero per-call overhead;
 * ``ServeSession.stats()`` exposes the two distinct warm-bytes keys
-  (session-cumulative ``warm_bytes`` vs mean ``warm_bytes_per_step``).
+  (session-cumulative ``warm_bytes`` vs mean ``warm_bytes_per_step``);
+* the decode step's phases reach the profiler's clock as ``kvswap.*``
+  annotations with no handle at all, nested as docs/observability.md
+  ("Profiler spans") says.
 """
 
+import glob
 import json
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -321,6 +326,34 @@ def test_disabled_path_is_a_true_noop(tiny_adapter, tiny_params):
     assert per_call < 20e-6, f"disabled add costs {per_call * 1e6:.2f} us"
 
 
+def test_disabled_phase_is_cheap_and_records_nothing():
+    # a phase on the null handle is one profiler annotation with no
+    # session: no span, no lock, no registry write
+    n = 50_000
+    t0 = time.perf_counter()
+    for i in range(n):
+        with NULL_OBS.phase("fetch", layer=i & 3) as ph:
+            ph.note(read_bytes=i)
+    per_call = (time.perf_counter() - t0) / n
+    assert len(NULL_OBS.tracer) == 0 and len(NULL_OBS.registry) == 0
+    assert per_call < 20e-6, f"disabled phase costs {per_call * 1e6:.2f} us"
+
+
+def test_enabled_phase_records_the_wall_span_under_todays_name():
+    obs = Observability()
+    with obs.phase("predict", layer=3):
+        pass
+    with obs.phase("fetch", layer=1) as ph:
+        ph.note(read_bytes=64)
+    with obs.phase("select_pull", layer=3):   # profiler-only phase
+        pass
+    spans = obs.tracer.spans()
+    assert [(s.name, s.track, s.cat) for s in spans] == [
+        ("predict L3", "layer3", "predict"), ("fetch L1", "layer1", "fetch")]
+    assert spans[1].args == {"layer": 1, "read_bytes": 64}
+    assert all(s.wall_t0 is not None and s.wall_dur >= 0 for s in spans)
+
+
 # ------------------------------------------------- serving replay trace
 
 def _serve(tiny_adapter, tiny_params, obs):
@@ -379,3 +412,105 @@ def test_report_cli(tiny_adapter, tiny_params, tmp_path):
     bad.write_text(json.dumps({"traceEvents": [
         {"name": "a", "ph": "X", "pid": 1, "tid": 1, "ts": 0}]}))
     assert report_main([str(bad), "--check"]) == 1
+
+
+# ------------------------------------------------- profiler spans
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a profiler session; return the ``kvswap.*`` host
+    events as ``{line: [(name, t0_ns, t1_ns, stats)]}`` (one line per
+    thread)."""
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for k, line in enumerate(plane.lines):
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+                   for e in line.events if e.name.startswith("kvswap.")]
+            if evs:
+                lines[(plane.name, k)] = evs
+    return lines
+
+
+def _inside(ev, outer):
+    return outer[1] <= ev[1] and ev[2] <= outer[2]
+
+
+@pytest.mark.parametrize("device_resident", [False, True])
+@pytest.mark.parametrize("async_io", [False, True])
+def test_decode_phases_reach_the_profiler_without_a_handle(
+        tiny_adapter, tiny_params, tmp_path, device_resident, async_io):
+    rng = np.random.default_rng(13)
+    prompt = np.asarray(rng.integers(0, 97, (2, 33)), dtype=np.int32)
+    calib = rng.standard_normal((1, 64, 2, 16)).astype(np.float32)
+    cfg = _engine_cfg(device_resident=device_resident, async_io=async_io)
+    with KVSwapEngine(tiny_adapter, tiny_params, cfg, batch=2,
+                      calib_k=calib) as eng:
+        logits = eng.prefill(prompt)
+        tok = np.asarray(np.argmax(logits, -1))
+
+        def three_steps():
+            nonlocal tok
+            for _ in range(3):
+                tok = np.asarray(np.argmax(eng.decode_step(tok), -1))
+
+        lines = _profiled(tmp_path, three_steps)
+        n_kv = len(eng.kv_layers)
+    main = [evs for evs in lines.values()
+            if any(e[0] == "kvswap.decode_step" for e in evs)]
+    assert len(main) == 1
+    main = main[0]
+    steps = [e for e in main if e[0] == "kvswap.decode_step"]
+    assert len(steps) == 3
+    assert [e[3]["step"] for e in steps] == [0, 1, 2]
+    fetch = "kvswap.fetch_wait" if async_io else "kvswap.fetch"
+    per_layer = ["kvswap.predict", "kvswap.select_pull", fetch, "kvswap.attn"]
+    if device_resident:
+        per_layer.append("kvswap.mirror_upload")
+    children = ("kvswap.select_pull", "kvswap.disk_read")
+    for st in steps:
+        inner = [e for e in main if e is not st and _inside(e, st)]
+        for name in per_layer:
+            got = sorted(e[3]["layer"] for e in inner if e[0] == name)
+            assert got == sorted(eng.kv_layers), (name, got)
+        assert sum(e[0] == "kvswap.step_prologue" for e in inner) == 1
+        # select_pull is the predict's child; the rest are siblings that
+        # never overlap
+        for e in inner:
+            if e[0] == "kvswap.select_pull":
+                assert any(p[0] == "kvswap.predict" and _inside(e, p)
+                           for p in inner)
+        sib = sorted((e for e in inner if e[0] not in children),
+                     key=lambda e: e[1])
+        assert all(a[2] <= b[1] for a, b in zip(sib, sib[1:])), sib
+        # the store reads are the fetch's children on whichever thread
+        # fetches
+        for e in inner:
+            if e[0] == "kvswap.disk_read":
+                assert not async_io
+                assert any(f[0] == fetch and _inside(e, f) for f in inner)
+    assert n_kv == 2
+    assert any(e[0] == "kvswap.disk_read" for evs in lines.values() for e in evs)
+    workers = [evs for evs in lines.values() if evs is not main]
+    prefetch = [e for evs in workers for e in evs if e[0] == "kvswap.prefetch"]
+    if async_io:
+        assert len(prefetch) == 3 * n_kv
+        assert not any(e[0] == "kvswap.prefetch" for e in main)
+    else:
+        assert not prefetch
+
+
+def test_serve_session_phases_reach_the_profiler(tiny_adapter, tiny_params,
+                                                 tmp_path):
+    lines = _profiled(tmp_path, lambda: _serve(tiny_adapter, tiny_params, None))
+    names = {e[0] for evs in lines.values() for e in evs}
+    assert {"kvswap.admit", "kvswap.sample", "kvswap.logits_pull",
+            "kvswap.decode_step", "kvswap.prefetch"} <= names
+    admits = [e for evs in lines.values() for e in evs if e[0] == "kvswap.admit"]
+    assert sorted(e[3]["rid"] for e in admits) == [0, 1, 2, 3]
