@@ -31,10 +31,11 @@ KV working set lives between steps:
   mirror updated by scatter-writing only newly fetched groups; the decode
   context is gathered on device by slot permutation
   (:meth:`~repro.models.transformer.TransformerAdapter.gather_context`),
-  fresh ``k_new/v_new`` accumulate in a device rolling buffer downloaded
-  once per completed group, and prediction is one fused dispatch.  Only
-  misses cross the host↔device boundary, so per-step upload bytes shrink by
-  the reuse hit rate (75–81 % of groups, Fig. 8).
+  fresh ``k_new/v_new`` accumulate in a device rolling buffer flushed at
+  the end of a step in which groups complete (one program and one download
+  for every completed layer and row), and prediction is one fused
+  dispatch.  Only misses cross the host↔device boundary, so per-step
+  upload bytes shrink by the reuse hit rate (75–81 % of groups, Fig. 8).
 * **host-gather** (``device_resident=False``, the seed behavior) — every
   layer re-materializes the full context on host and re-uploads it.  Kept as
   the A/B control; decoded tokens are **bit-identical** between the two.
@@ -208,6 +209,7 @@ class StepStats:
     pred_cur_groups: int = 0         # |cur| summed over (layer, row)
     stale_groups: int = 0            # reuse-resident but unselected this step
     resident_groups: int = 0         # reuse-resident at selection time
+    flushed_groups: int = 0          # (KV layer, row) groups written to disk this step
 
     @property
     def overlap_saved_seconds(self) -> float:
@@ -275,6 +277,7 @@ def summarize_steps(steps: Sequence[StepStats]) -> dict:
         "pred_precision": shared / prev if prev else 0.0,
         "pred_recall": shared / cur if cur else 0.0,
         "stale_group_rate": stale / resident if resident else 0.0,
+        "flushed_groups": sum(s.flushed_groups for s in steps),
         **{f"step_seconds_{k}": v for k, v in tails.items()},
     }
 
@@ -289,9 +292,38 @@ def _klr_append(k_lr: jax.Array, rows: jax.Array, start: jax.Array) -> jax.Array
 def _klr_append_row(k_lr: jax.Array, rows: jax.Array, bi: jax.Array,
                     start: jax.Array) -> jax.Array:
     """Write ``rows [1, n, r]`` into row ``bi`` of ``k_lr [B, cap, r]`` at
-    token offset ``start`` — the per-row flush/admission unit of continuous
-    batching (rows hit group boundaries at different steps)."""
+    token offset ``start`` — the admission unit of continuous batching."""
     return jax.lax.dynamic_update_slice(k_lr, rows, (bi, start, 0))
+
+
+@functools.partial(jax.jit, static_argnames=("dtype",), donate_argnums=(0,))
+def _flush_groups(k_lr: tuple, a: jax.Array, done: jax.Array, start: jax.Array,
+                  groups_k, groups_v, *, dtype):
+    """Every group a decode step completed, in one program.
+
+    ``groups_k[j]``/``groups_v[j]`` hold KV layer ``j``'s ``[B, G, H_kv, d]``
+    groups (the device rolling tails, or the host rolling buffers stacked
+    ``[L_kv, B, G, H_kv, d]``); ``done [L_kv, B]`` marks the (layer, row)
+    groups that completed; ``start [B]`` is each row's ``k_lr`` watermark.
+    Each completed group, cast to the storage ``dtype`` exactly as the disk
+    stores it, is compressed and written into ``k_lr[j]`` at its row's
+    watermark; other rows keep their contents.  Returns ``(kv, k_lr)``:
+    ``kv`` is the cast K and V, each stacked ``[L_kv, B, G, H_kv, d]``, for
+    the disk write, or ``None`` when ``groups_v`` is empty (the host path
+    has written its groups already)."""
+    k = [groups_k[j].astype(dtype) for j in range(len(k_lr))]
+    b, g, hk, d = k[0].shape
+    adapter = LowRankAdapter(a=a, n_kv_heads=hk, head_dim=d)
+    new = []
+    for j, kl in enumerate(k_lr):
+        rows = compress_k(k[j].astype(jnp.float32), adapter)        # [B, G, r]
+        # rows that did not complete point past the end and are dropped
+        t = jnp.where(done[j], start, kl.shape[1])[:, None] + jnp.arange(g)
+        new.append(kl.at[jnp.arange(b)[:, None], t].set(rows, mode="drop"))
+    if not groups_v:
+        return None, tuple(new)
+    v = [groups_v[j].astype(dtype) for j in range(len(k_lr))]
+    return (jnp.stack(k), jnp.stack(v)), tuple(new)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -1049,20 +1081,20 @@ class KVSwapEngine:
 
             t_compute: list[float] = []
             t_io: list[float] = []
-            flush_rows: list[tuple[int, int, jax.Array]] = []   # (layer, row, k_lr rows)
+            # (KV layer, row) groups completed this step, flushed at its end
+            done = np.zeros((len(self.kv_layers), b), dtype=bool)
             if self.prefetcher is not None:
-                x, io_wait = self._layers_async(x, pos, valid, t_compute, t_io, flush_rows)
+                x, io_wait = self._layers_async(x, pos, valid, t_compute, t_io, done)
             else:
-                x, io_wait = self._layers_sync(x, pos, valid, t_compute, t_io, flush_rows)
+                x, io_wait = self._layers_sync(x, pos, valid, t_compute, t_io, done)
 
-            with obs.phase("klr_append"):
-                for layer, bi, rows in flush_rows:
-                    self.k_lr[layer] = _klr_append_row(
-                        self.k_lr[layer], rows, jnp.int32(bi),
-                        jnp.int32(self.row_valid[bi]))
-                for bi in {bi for _, bi, _ in flush_rows}:
-                    self.row_valid[bi] += self.cfg.group_size
-                self.row_seq[active] += 1
+            n_flush = int(done.sum())
+            if n_flush:
+                with obs.phase("spill", groups=n_flush):
+                    logits = self._flush(done, valid, x)
+            else:
+                logits = self.model.logits(self.params, x)
+            self.row_seq[active] += 1
 
             stats = StepStats()
             stats.io_seconds = sum(t_io)
@@ -1081,12 +1113,38 @@ class KVSwapEngine:
             stats.pred_cur_groups = qc.cur_groups
             stats.stale_groups = qc.stale_groups
             stats.resident_groups = qc.resident_groups
-            logits = self.model.logits(self.params, x)
-            # host time to dispatch the step: the logits are not waited for
+            stats.flushed_groups = n_flush
+            # host time of the step: the logits are not waited for
             stats.wall_seconds = time.perf_counter() - t0
             self.step_log.append(stats)
             if obs.enabled:
                 self._obs_step(stats, t_compute, t_io)
+        return logits
+
+    def _flush(self, done: np.ndarray, valid: jax.Array, x: jax.Array) -> jax.Array:
+        """Flush every group the step completed; returns the step's logits.
+
+        One program appends the completed groups' compressed keys to
+        ``k_lr`` and casts them for the disk; the head is dispatched behind
+        it, so the device computes the logits while the host waits on the
+        one download and writes the groups, layer by layer and row by row.
+        The host path wrote its groups during the layer loop; its rolling
+        buffers still hold them, and reach the program in one upload."""
+        if self.device_resident:
+            groups_k, groups_v = tuple(self._tail_k), tuple(self._tail_v)
+        else:
+            groups_k = jnp.asarray(np.stack([rb.k for rb in self.rolling]))
+            groups_v = ()
+            self._h2d_step += groups_k.nbytes
+        kv, k_lr = _flush_groups(tuple(self.k_lr), self.adapter.a, jnp.asarray(done),
+                                 valid, groups_k, groups_v, dtype=self.cfg.np_dtype)
+        self.k_lr = list(k_lr)
+        logits = self.model.logits(self.params, x)
+        if kv is not None:
+            k_np, v_np = jax.device_get(kv)
+            for j, bi in zip(*np.nonzero(done)):
+                self.managers[j].spill_group_row(int(bi), k_np[j, bi], v_np[j, bi])
+        self.row_valid[done.any(axis=0)] += self.cfg.group_size
         return logits
 
     def _obs_step(self, stats: StepStats, t_compute: Sequence[float],
@@ -1195,15 +1253,13 @@ class KVSwapEngine:
         return x
 
     def _kv_layer(self, layer: int, j: int, x: jax.Array, pos: jax.Array, table,
-                  t_compute: list[float], flush_rows: list) -> jax.Array:
+                  t_compute: list[float], done: np.ndarray) -> jax.Array:
         if self.device_resident:
-            return self._kv_layer_device(layer, j, x, pos, table, t_compute,
-                                         flush_rows)
-        return self._kv_layer_host(layer, j, x, pos, table, t_compute,
-                                   flush_rows)
+            return self._kv_layer_device(layer, j, x, pos, table, t_compute, done)
+        return self._kv_layer_host(layer, j, x, pos, table, t_compute, done)
 
     def _kv_layer_host(self, layer: int, j: int, x: jax.Array, pos: jax.Array,
-                       table, t_compute: list[float], flush_rows: list) -> jax.Array:
+                       table, t_compute: list[float], done: np.ndarray) -> jax.Array:
         """Seed behavior (the A/B control): host concat + full upload."""
         cfg = self.cfg
         with self.obs.phase("attn", layer=layer) as ph:
@@ -1222,22 +1278,19 @@ class KVSwapEngine:
                 np.asarray(jax.device_get(v_new), dtype=cfg.np_dtype),
                 self._step_active,
             )
-            for bi, k_g, _ in completed:
-                # compress the completed group's keys exactly as stored on disk
-                k_gj = jnp.asarray(k_g[None], dtype=jnp.float32)
-                self._h2d_step += k_gj.nbytes
-                flush_rows.append((j, bi, compress_k(k_gj, self.adapter)))
+            for bi, _, _ in completed:
+                done[j, bi] = True
         return x
 
     def _kv_layer_device(self, layer: int, j: int, x: jax.Array, pos: jax.Array,
-                         table, t_compute: list[float], flush_rows: list) -> jax.Array:
+                         table, t_compute: list[float], done: np.ndarray) -> jax.Array:
         """Device-resident hot path: only fetch misses cross host→device.
 
         The reuse mirror is brought up to date with one scatter of the
         fetch's ``new_groups`` delta, the context is gathered on device by
         the step's slot permutation, and the freshly decoded ``k_new/v_new``
-        stay on device in a rolling mirror until the group completes (one
-        download per ``G`` steps feeds the disk spill + ``k_lr`` append).
+        stay on device in a rolling mirror until the group completes; the
+        row is then marked in ``done`` for the step-end flush (:meth:`_flush`).
         Feeds the *same* compiled ``decode_block`` as the host path with
         bit-identical inputs, so tokens match the control exactly.
         """
@@ -1284,23 +1337,9 @@ class KVSwapEngine:
             fidx = jnp.asarray(mgr.rolling.fills.astype(np.int32))
             self._tail_k[j] = _tail_write(self._tail_k[j], k_new, fidx, act)
             self._tail_v[j] = _tail_write(self._tail_v[j], v_new, fidx, act)
-            completed = mgr.rolling.advance_rows(self._step_active)
+            done[j, mgr.rolling.advance_rows(self._step_active)] = True
             self._charge_layer_compute(j, k_ctx.shape[1] + 1, t_compute)
             ph.note(modeled_compute_s=t_compute[-1])
-        if completed:
-            with self.obs.phase("spill", layer=layer):
-                for bi in completed:
-                    # row's group complete: cast exactly as the host path
-                    # stores it; one download feeds the disk spill, the k_lr
-                    # append compresses straight from the device copy
-                    grp_k = self._tail_k[j][bi].astype(cfg.np_dtype)
-                    grp_v = self._tail_v[j][bi].astype(cfg.np_dtype)
-                    k_np, v_np = (np.asarray(a)
-                                  for a in jax.device_get((grp_k, grp_v)))
-                    mgr.spill_group_row(bi, k_np, v_np)
-                    flush_rows.append(
-                        (j, bi, compress_k(grp_k[None].astype(jnp.float32),
-                                           self.adapter)))
         return x
 
     def _charge_layer_compute(self, j: int, n_ctx: int,
@@ -1315,7 +1354,7 @@ class KVSwapEngine:
         )
 
     # -- synchronous path ------------------------------------------------
-    def _layers_sync(self, x, pos, valid, t_compute, t_io, flush_rows):
+    def _layers_sync(self, x, pos, valid, t_compute, t_io, done):
         """Seed behavior: predict + fetch inline, on the critical path."""
         io_wait = 0.0
         x_prev = x
@@ -1338,11 +1377,11 @@ class KVSwapEngine:
                 ph.note(modeled_io_s=t_io[-1], read_bytes=tr.read_bytes,
                         warm_bytes=tr.warm_bytes)
             x_prev = x
-            x = self._kv_layer(layer, j, x, pos, table, t_compute, flush_rows)
+            x = self._kv_layer(layer, j, x, pos, table, t_compute, done)
         return x, io_wait
 
     # -- asynchronous pipeline (§3.3 / §3.4) ----------------------------
-    def _layers_async(self, x, pos, valid, t_compute, t_io, flush_rows):
+    def _layers_async(self, x, pos, valid, t_compute, t_io, done):
         """Issue layer *i+1*'s fetch as soon as its prediction source exists.
 
         With ``predict_from="prev"``, layer *L* is scored from layer *L−1*'s
@@ -1379,7 +1418,7 @@ class KVSwapEngine:
                     io_wait += time.perf_counter() - w0
                     t_io.append(res.io_seconds)
                     ph.note(modeled_io_s=res.io_seconds)
-                x = self._kv_layer(layer, j, x, pos, res.table, t_compute, flush_rows)
+                x = self._kv_layer(layer, j, x, pos, res.table, t_compute, done)
         except BaseException:
             buf.drain()   # never leave staged futures behind on an error
             raise

@@ -238,8 +238,8 @@ class KVCacheManager:
 
         Counterpart of :meth:`append_token_rows` for the device path: the
         rolling tokens lived on device, were counted by
-        ``RollingBuffer.advance_rows()``, and are downloaded once per ``G``
-        steps as this ``[G, H_kv, d]`` pair.  Rows flush independently —
+        ``RollingBuffer.advance_rows()``, and reach the host in the engine's
+        one step-end download as this ``[G, H_kv, d]`` pair.  Rows flush independently —
         continuous batching admits them at different offsets.
         """
         self.store.append_group_row(self.layer, batch_idx, k_group, v_group)

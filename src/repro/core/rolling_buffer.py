@@ -64,8 +64,8 @@ class RollingBuffer:
         """Count one appended token per active row without a host copy.
 
         The device-resident decode path keeps ``k_new/v_new`` on device (a
-        device rolling mirror in the engine) and only downloads a completed
-        group at flush time; this keeps ``fills`` — which the mapping-table
+        device rolling mirror in the engine) and only downloads completed
+        groups in its step-end flush; this keeps ``fills`` — which the mapping-table
         rebuild reads — in sync without a per-token device→host transfer.
         Returns the rows whose group completed (caller must spill the device
         group via :meth:`KVCacheManager.spill_group_row`); the host ``k/v``

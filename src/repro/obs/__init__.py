@@ -67,16 +67,16 @@ __all__ = [
 ]
 
 
-# phase -> (wall-span prefix, category) of the phases the tracer records,
-# as "<prefix> L{layer}" on the "layer{layer}" lane (the prefetch worker's
-# on its thread's lane); every other phase is on the profiler's clock only
+# phase -> (wall-span prefix, category) of the per-layer phases the tracer
+# records, as "<prefix> L{layer}" on the "layer{layer}" lane (the prefetch
+# worker's on its thread's lane); every other phase, the step-end "spill"
+# among them, is on the profiler's clock only
 _WALL_SPANS = {
     "predict": ("predict", "predict"),
     "fetch": ("fetch", "fetch"),
     "fetch_wait": ("wait", "fetch"),
     "mirror_upload": ("upload", "attn"),
     "attn": ("attn", "attn"),
-    "spill": ("spill", "attn"),
     "prefetch": ("fetch", "prefetch"),
 }
 

@@ -257,3 +257,131 @@ class TestSatellites:
         rb = RollingBuffer(batch=2, group_size=4, n_kv_heads=2, head_dim=8)
         assert [rb.advance() for _ in range(4)] == [False, False, False, True]
         assert rb.fill == 0
+
+
+def _greedy_staggered(eng, prompts, admit_at, n_steps):
+    """Admit ``prompts[bi]`` into row ``bi`` before step ``admit_at[bi]`` and
+    decode greedily; returns the ``[n_steps, B]`` tokens fed back."""
+    tok = np.zeros(eng.batch, np.int64)
+    out = []
+    for step in range(n_steps):
+        for bi, at in enumerate(admit_at):
+            if at == step:
+                tok[bi] = int(np.argmax(np.asarray(eng.admit_row(bi, prompts[bi]))))
+        logits = np.asarray(eng.decode_step(tok))
+        tok = np.where(eng.row_active, logits.argmax(-1), tok)
+        out.append(tok.copy())
+    return np.stack(out)
+
+
+def _tiny_model(n_layers):
+    cfg = ModelConfig(name="tiny", arch_type="dense", n_layers=n_layers,
+                      d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
+                      d_ff=128, vocab_size=97)
+    return TransformerAdapter(cfg), init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _assert_klr_compresses_store(eng):
+    """Each row's ``k_lr`` holds its stored keys, compressed, up to its
+    watermark, and nothing past it."""
+    from repro.core.lowrank import compress_k
+
+    for j, k_lr in enumerate(eng.k_lr):
+        k_lr = np.asarray(k_lr)
+        for bi in range(eng.batch):
+            n = int(eng.row_valid[bi])
+            assert n == eng.store.n_groups[j, bi] * eng.cfg.group_size
+            k = eng.store._mm[j, bi, : n // eng.cfg.group_size, :, 0]
+            want = compress_k(jnp.asarray(k.reshape(1, n, *k.shape[2:])), eng.adapter)
+            np.testing.assert_array_equal(k_lr[bi, :n], np.asarray(want)[0])
+            np.testing.assert_array_equal(k_lr[bi, n:], 0)
+
+
+class TestStepEndFlush:
+    """Every group a decode step completes is flushed at the step's end by
+    one program and one download, whatever rows and layers complete."""
+
+    @pytest.mark.parametrize("async_io", [False, True])
+    @pytest.mark.parametrize("kv_bits", [16, 8])
+    def test_staggered_rows_match_host_gather(self, setup, async_io, kv_bits):
+        """Rows admitted at offsets that complete groups in different steps:
+        tokens, ``k_lr`` and the store's bytes equal the host-gather
+        control's."""
+        cfg, params, adapter, _, calib = setup
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(0, cfg.vocab_size, n) for n in (37, 30, 24)]
+        got = {}
+        for dr in (False, True):
+            with make_engine(adapter, params, calib, batch=3, async_io=async_io,
+                             kv_bits=kv_bits, device_resident=dr) as eng:
+                toks = _greedy_staggered(eng, prompts, (0, 2, 3), 12)
+                flushed = [s.flushed_groups for s in eng.step_log]
+                if kv_bits == 16:
+                    _assert_klr_compresses_store(eng)
+                got[dr] = (toks, [np.asarray(k) for k in eng.k_lr],
+                           np.array(eng.store._mm), eng.store.n_groups.copy(),
+                           None if kv_bits == 16 else eng.store._scales.copy())
+        n_kv = 2
+        # the schedule completes groups of some rows only, in several steps
+        assert any(0 < f < 3 * n_kv for f in flushed)
+        assert sum(f > 0 for f in flushed) >= 4
+        for a, b in zip(got[False], got[True]):
+            if isinstance(a, list):
+                for x, y in zip(a, b):
+                    np.testing.assert_array_equal(x, y)
+            elif a is not None:
+                np.testing.assert_array_equal(a, b)
+
+    @pytest.mark.parametrize("batch,n_layers", [(1, 1), (3, 2), (4, 3)])
+    def test_flush_step_makes_one_dispatch_and_one_download(
+            self, monkeypatch, batch, n_layers):
+        from repro.core import engine as engine_mod
+
+        model, params = _tiny_model(n_layers)
+        rng = np.random.default_rng(2)
+        calib = rng.standard_normal((256, 2, 16)).astype(np.float32)
+        prompt = rng.integers(0, 97, (batch, 37)).astype(np.int32)
+        calls = {"flush": 0, "get": 0, "append_row": 0}
+
+        def counted(fn, key):
+            def wrapper(*args, **kwargs):
+                calls[key] += 1
+                return fn(*args, **kwargs)
+            return wrapper
+
+        with make_engine(model, params, calib, batch=batch) as eng:
+            logits = eng.prefill(prompt)
+            monkeypatch.setattr(engine_mod, "_flush_groups",
+                                counted(engine_mod._flush_groups, "flush"))
+            monkeypatch.setattr(engine_mod, "_klr_append_row",
+                                counted(engine_mod._klr_append_row, "append_row"))
+            monkeypatch.setattr(jax, "device_get", counted(jax.device_get, "get"))
+            per_step = []
+            for _ in range(5):
+                calls.update(flush=0, get=0, append_row=0)
+                logits = eng.decode_step(np.asarray(jnp.argmax(logits, axis=-1)))
+                per_step.append(dict(calls))
+            flushed = [s.flushed_groups for s in eng.step_log]
+        # a tail of 37 % 4 = 1 token: the third step completes every row;
+        # each step's other downloads are the layers' selections
+        for step, c in enumerate(per_step):
+            flush = step == 2
+            assert c == {"flush": int(flush), "get": n_layers + flush,
+                         "append_row": 0}, (step, c)
+        assert flushed == [0, 0, batch * n_layers, 0, 0]
+
+    @pytest.mark.parametrize("device_resident", [False, True])
+    def test_flushed_groups_counts_rows_times_kv_layers(self, setup,
+                                                        device_resident):
+        from repro.core.engine import summarize_steps
+
+        cfg, params, adapter, prompt, calib = setup
+        with make_engine(adapter, params, calib,
+                         device_resident=device_resident) as eng:
+            eng.generate(prompt, 9)
+            flushed = [s.flushed_groups for s in eng.step_log]
+            n_kv = len(eng.kv_layers)
+            # prompt 37: the rows complete a group on steps 3 and 7
+            assert flushed == [0, 0, 2 * n_kv, 0, 0, 0, 2 * n_kv, 0, 0]
+            assert summarize_steps(eng.step_log)["flushed_groups"] == 4 * n_kv
+            assert eng.store.n_groups.tolist() == [[11, 11]] * n_kv

@@ -480,6 +480,13 @@ def test_decode_phases_reach_the_profiler_without_a_handle(
             got = sorted(e[3]["layer"] for e in inner if e[0] == name)
             assert got == sorted(eng.kv_layers), (name, got)
         assert sum(e[0] == "kvswap.step_prologue" for e in inner) == 1
+        # the prompt's 33 tokens leave a tail of 1, so the third step
+        # completes a group in every row and layer: one step-level spill
+        flush = [e for e in inner
+                 if e[0] == "kvswap.spill" and "layer" not in e[3]]
+        want = [2 * n_kv] if st[3]["step"] == 2 else []
+        assert [e[3]["groups"] for e in flush] == want
+        assert not any(e[0] == "kvswap.klr_append" for e in inner)
         # select_pull is the predict's child; the rest are siblings that
         # never overlap
         for e in inner:

@@ -19,6 +19,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 # Qwen3-8B attention widths (repro.configs.qwen3_8b), engine defaults
@@ -112,3 +113,22 @@ def test_decode_block_compiles_at_qwen3_8b_widths(one_chip, use_pallas):
     assert _has_kernel(c) == use_pallas
     # one layer's float32 weights are the arguments: 0.78 GB of 16 GB
     assert c.memory_analysis().argument_size_in_bytes < 1 << 30
+
+
+def test_step_end_flush_compiles_at_qwen3_8b_widths(one_chip):
+    """The decode step's group flush at the benchmark's shapes: 8 rows, 4 KV
+    layers, a 16388-token ``k_lr`` per layer, bfloat16 storage.  ``k_lr`` is
+    donated, so the appends are made in place."""
+    from repro.core.engine import _flush_groups
+
+    s = functools.partial(_shape, one_chip)
+    b, layers, cap = 8, 4, 16388
+    k_lr = tuple(s((b, cap, R)) for _ in range(layers))
+    tails = tuple(s((b, G, HK, D)) for _ in range(layers))
+    c = _flush_groups.lower(k_lr, s((HK * D, R)), s((layers, b), jnp.bool_),
+                            s((b,), jnp.int32), tails, tails,
+                            dtype=np.dtype(jnp.bfloat16)).compile()
+    mem = c.memory_analysis()
+    klr_bytes = layers * b * cap * R * 4
+    assert mem.alias_size_in_bytes >= klr_bytes
+    assert mem.temp_size_in_bytes < klr_bytes // layers
