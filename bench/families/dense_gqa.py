@@ -1,7 +1,33 @@
-"""Weights for a dense GQA configuration, made on the device in one jitted
-call from the configuration's own ``weights.seed``, in the layout
-``repro.models.transformer`` serves.  A run's ``--seed`` draws only the
-token ids and their order, so every seed serves the same model.
+"""The dense GQA family: a decoder of Llama-style blocks (grouped-query
+attention, optional per-head RMSNorm on queries and keys, rotary
+embedding, SwiGLU MLP), every layer alike.
+
+A family is what ``bench/`` knows of a model's architecture, found by the
+``family`` a configuration names (``spec.family``):
+
+    model(cfg)                      the adapter the engine serves
+    params(cfg)                     weights on the device, from weights.seed
+    calibration_layer(cfg)          the KV layer whose K fits the adapter
+    decode_token_flops(cfg, n)      operations to decode one token that
+                                    attends ``n`` earlier tokens per layer
+    decode_programs(cfg, batch, context, itemsize)
+                                    {jitted decode program: (operations,
+                                    least HBM bytes) of one call}
+
+Each layer runs once per decode step.  A program that layers of different
+cost share under one name (``decode_block`` is jitted with a static
+``layer``, so dense, expert and windowed layers all appear in a trace as
+``jit_decode_block``) reports the mean cost of one call over those layers.
+
+Operations and bytes are computed from shapes alone: a matmul of [m, k] by
+[k, n] is 2*m*k*n operations, and bytes are the least a call must move
+through HBM: every weight it reads once, the K/V context it attends over,
+and its activations in and out.
+
+The weights are made on the device in one jitted call from the
+configuration's own ``weights.seed``, in the layout
+``repro.models.transformer`` serves.  A run's ``--seed`` draws only what
+its traffic kind draws, so every seed serves the same model.
 
 I.i.d. Gaussian weights give every key a score of about N(0, 1) against
 every query, over thousands of keys: attention is flat, and K is full rank.
@@ -168,6 +194,70 @@ def params_fn(cfg: dict):
     return jax.jit(build)
 
 
-def make(cfg: dict):
+def params(cfg: dict):
     """Params for ``cfg`` from its ``weights.seed``, on the default device."""
     return jax.block_until_ready(params_fn(cfg)(seed_key(cfg["weights"]["seed"])))
+
+
+def model(cfg: dict):
+    from repro.models.transformer import ModelConfig, TransformerAdapter
+
+    return TransformerAdapter(ModelConfig(
+        name=cfg["name"], arch_type="dense", n_layers=cfg["num_hidden_layers"],
+        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
+        rope_theta=cfg["rope_theta"], qk_norm=cfg["qk_norm"],
+        tie_embeddings=cfg["tie_word_embeddings"], source=cfg["source"]))
+
+
+def calibration_layer(cfg: dict) -> int:
+    """Every layer's K comes from the same per-head maps (see above)."""
+    return 0
+
+
+def layer_matmul_params(cfg: dict) -> int:
+    """Weights one layer multiplies by: Q, K, V, O and the three SwiGLU
+    matrices."""
+    d, hd = cfg["hidden_size"], cfg["head_dim"]
+    nh, nkv, f = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["intermediate_size"]
+    return d * nh * hd + 2 * d * nkv * hd + nh * hd * d + 3 * d * f
+
+
+def layer_params(cfg: dict) -> int:
+    """Every weight of a layer: the matmuls plus the norm scales."""
+    extra = 2 * cfg["hidden_size"] + (2 * cfg["head_dim"] if cfg["qk_norm"] else 0)
+    return layer_matmul_params(cfg) + extra
+
+
+def attention_flops(cfg: dict, queries: int, keys: int) -> int:
+    """Scores and the weighted sum: two [H, hd] x [hd, keys] products per query."""
+    return 4 * queries * cfg["num_attention_heads"] * cfg["head_dim"] * keys
+
+
+def decode_block_flops(cfg: dict, batch: int, context: int) -> int:
+    """One call of the jitted decode layer: ``batch`` tokens, each attending
+    ``context`` gathered tokens plus itself."""
+    return 2 * batch * layer_matmul_params(cfg) + attention_flops(cfg, batch, context + 1)
+
+
+def decode_block_bytes(cfg: dict, batch: int, context: int, itemsize: int) -> int:
+    """Least HBM traffic of that call: the layer's weights, the gathered K
+    and V, the hidden state in and out, and the new K and V out."""
+    kv_row = cfg["num_key_value_heads"] * cfg["head_dim"]
+    weights = layer_params(cfg) * itemsize
+    context_kv = 2 * batch * context * kv_row * itemsize
+    activations = 2 * batch * cfg["hidden_size"] * itemsize + 2 * batch * kv_row * itemsize
+    return weights + context_kv + activations
+
+
+def decode_programs(cfg: dict, batch: int, context: int, itemsize: int) -> dict:
+    """Every layer runs ``decode_block``, jitted as ``jit_decode_block``."""
+    return {"jit_decode_block": (decode_block_flops(cfg, batch, context),
+                                 decode_block_bytes(cfg, batch, context, itemsize))}
+
+
+def decode_token_flops(cfg: dict, attended: int) -> int:
+    """Every layer, then the output head."""
+    per_layer = 2 * layer_matmul_params(cfg) + attention_flops(cfg, 1, attended + 1)
+    return cfg["num_hidden_layers"] * per_layer + 2 * cfg["hidden_size"] * cfg["vocab_size"]

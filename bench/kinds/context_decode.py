@@ -5,8 +5,11 @@ every context as turns of ``turn_tokens`` through the prefix cache, like a
 transcript arriving in segments: each turn asks for one token, the first
 turn is a cold admission and each later one restores the turn before it
 and prefills the new segment.  The last turn asks for ``max_new`` tokens,
-and in the window every slot decodes.  Every seed gets the same lengths;
-the run's seed draws the token ids and the order of the lengths.
+and in the window every slot decodes.  The contexts' token ids are drawn
+from the mix's own ``content_seed``, so every run builds and decodes the
+same contexts and does the same work: with random ids the share of KV
+groups the reuse buffer serves, and so the bytes fetched per token, move
+with the ids.  The run's seed orders the contexts over the slots.
 """
 
 from __future__ import annotations
@@ -19,10 +22,13 @@ from bench.serve import Client, CompileCounter, Tracer, Window
 
 
 def rows(traffic: dict, vocab: int, seed: int) -> list[np.ndarray]:
-    """One token-id context per slot; the seed orders the lengths."""
-    rng = np.random.default_rng(int(seed))
+    """One token-id context per slot: the mix's contexts, in the order the
+    seed draws."""
+    rng = np.random.default_rng(int(traffic["content_seed"]))
     lengths = rng.permutation(np.asarray(traffic["contexts"], np.int64))
-    return [rng.integers(0, vocab, int(n)).astype(np.int64) for n in lengths]
+    ctxs = [rng.integers(0, vocab, int(n)).astype(np.int64) for n in lengths]
+    order = np.random.default_rng(int(seed)).permutation(len(ctxs))
+    return [ctxs[i] for i in order]
 
 
 def build_contexts(drv: Client, ctxs: list[np.ndarray], turn: int, max_new: int) -> list:

@@ -4,7 +4,8 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 One run is one process: turn on JAX's compilation cache inside the
-checkout, name the device, make the configuration's weights on it, warm
+checkout, name the device, make the configuration's model and weights on
+it (its family's module, found by name in ``bench/families/``), warm
 every shape the cell's traffic uses (the traffic kind's module, found by
 name in ``bench/kinds/``, draws its requests from the seed), run the
 traffic for ``--seconds``, check the
@@ -77,29 +78,21 @@ def enable_cache() -> None:
     jax.config.update("jax_compilation_cache_max_size", -1)
 
 
-def model_config(cfg: dict):
-    from repro.models.transformer import ModelConfig
-
-    return ModelConfig(
-        name=cfg["name"], arch_type="dense", n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        rope_theta=cfg["rope_theta"], qk_norm=cfg["qk_norm"],
-        tie_embeddings=cfg["tie_word_embeddings"], source=cfg["source"])
-
-
-def fit_adapter(model, params, cfg, rank):
-    """KVSwap's offline adapter: SVD of layer 0's K on a calibration prompt
-    drawn from the configuration's weight seed, so every run fits the same."""
+def fit_adapter(model, params, cfg, layer, rank):
+    """KVSwap's offline adapter: SVD of the family's calibration layer's K
+    on a calibration prompt drawn from the configuration's weight seed, so
+    every run fits the same."""
     import jax.numpy as jnp
     import numpy as np
     from repro.core.lowrank import fit_adapter as fit
 
     n = cfg["calibration_tokens"]
-    calib = np.random.default_rng(cfg["weights"]["seed"]).integers(0, cfg["vocab_size"], (1, n))
+    calib = np.random.default_rng(cfg["weights"]["seed"]).integers(0, model.vocab_size, (1, n))
     x = model.embed(params, jnp.asarray(calib))
-    _, k, _ = model.prefill_block(params, 0, x, jnp.arange(n)[None])
+    pos = jnp.arange(n)[None]
+    for i in range(layer):
+        x = model.prefill_block(params, i, x, pos)[0]
+    _, k, _ = model.prefill_block(params, layer, x, pos)
     return fit(np.asarray(k), rank=rank)
 
 
@@ -121,19 +114,21 @@ class Session:
 def build_session(cell) -> Session:
     """The configuration's weights on the device, the low-rank adapter, and a
     ``ServeSession`` with a prefix cache, at the cell's settings; every
-    other engine switch stays at the program's default."""
+    other engine switch stays at the program's default.  The model, its
+    weights and the calibration layer come from the configuration's
+    family."""
     from repro.cache import PrefixCache, PrefixCacheConfig
     from repro.core.engine import EngineConfig
-    from repro.models.transformer import TransformerAdapter
     from repro.serving.api import ServeSession
 
-    from bench import serve, weights
+    from bench import serve, spec
 
     cfg, traffic, eng = cell.config, cell.traffic, cell.engine
+    family = spec.family(cfg["family"])
     compiles = serve.CompileCounter()
-    params = weights.make(cfg)
-    model = TransformerAdapter(model_config(cfg))
-    adapter = fit_adapter(model, params, cfg, eng["rank"])
+    params = family.params(cfg)
+    model = family.model(cfg)
+    adapter = fit_adapter(model, params, cfg, family.calibration_layer(cfg), eng["rank"])
     ecfg = EngineConfig(group_size=eng["group_size"], n_select=eng["n_select"],
                         rank=eng["rank"], reuse_capacity=eng["reuse_capacity"],
                         kv_bits=eng["kv_bits"], warm_budget_bytes=eng["warm_budget_bytes"],

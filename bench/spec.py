@@ -5,14 +5,16 @@ configuration and a traffic mix, and each metric name is a reader file.
 Everything that belongs to one of them lives in a file of its own:
 
     bench/configs/<config>.json      sizes, engine settings, weight structure
+    bench/families/<family>.py       what the harness knows of an architecture,
+                                     named by a config: model, weights, counts
     bench/traffic/<traffic>.json     parameters of one mix, with its ``kind``
     bench/kinds/<kind>.py            how a kind of mix is offered: window(...)
     bench/checks/<cell>.json         limits of the numbers `correct` compares
     bench/metrics/<metric>.py        reader: run -> float | None
     bench/reference/<name>.py        plain reference named by a config
 
-A later cell, configuration, mix or metric is added by adding files and
-entries; a mix of a kind already there is a data file alone.
+A later cell, configuration, family, mix or metric is added by adding
+files and entries; a mix of a kind already there is a data file alone.
 """
 
 from __future__ import annotations
@@ -55,6 +57,13 @@ def peaks(device_kind: str) -> dict:
     return table["devices"][device_kind]
 
 
+def roofline_seconds(flops: float, nbytes: float, peak: dict) -> tuple[float, str]:
+    """The least time the chip could take, and which bound sets it."""
+    t_flops = flops / peak["bf16_flops_per_s"]
+    t_bytes = nbytes / peak["hbm_bytes_per_s"]
+    return (t_bytes, "memory") if t_bytes >= t_flops else (t_flops, "compute")
+
+
 def _load_file(path: str, modname: str):
     spec = importlib.util.spec_from_file_location(modname, path)
     mod = importlib.util.module_from_spec(spec)
@@ -72,6 +81,12 @@ def kind(name: str):
     """The module ``bench/kinds/<name>.py`` that offers a kind of mix."""
     path = os.path.join(BENCH_DIR, "kinds", f"{name}.py")
     return _load_file(path, "bench_kind_" + name)
+
+
+def family(name: str):
+    """The module ``bench/families/<name>.py`` of a model family."""
+    path = os.path.join(BENCH_DIR, "families", f"{name}.py")
+    return _load_file(path, "bench_family_" + name)
 
 
 def reference(name: str):

@@ -1,11 +1,13 @@
 """Order statistics and the request timings built on them.
 
 ``percentile`` interpolates linearly between closest ranks on the sorted
-sample (numpy's default ``method="linear"``), in plain Python.
+sample (numpy's default ``method="linear"``), in plain Python.  ``spread``
+is a set of runs' spread as the bounds in ``BENCHMARK.json`` are set from.
 """
 
 from __future__ import annotations
 
+import statistics
 from typing import Sequence
 
 
@@ -35,3 +37,17 @@ def inter_token_gaps(requests, t_open: float, t_close: float) -> list[float]:
 
 def tokens_in(requests, t_open: float, t_close: float) -> int:
     return sum(1 for r in requests for t in r.token_t if t_open <= t <= t_close)
+
+
+def spread(xs: Sequence[float]) -> float:
+    """The distance between the first and the third quartile of
+    ``statistics.quantiles(xs, n=4)``, over the median."""
+    q1, q2, q3 = statistics.quantiles(xs, n=4)
+    return (q3 - q1) / q2
+
+
+def trimmed_spread(xs: Sequence[float]) -> float:
+    """``spread`` without the value farthest from the median."""
+    mid = statistics.median(xs)
+    far = max(range(len(xs)), key=lambda i: abs(xs[i] - mid))
+    return spread([x for i, x in enumerate(xs) if i != far])

@@ -4,7 +4,9 @@ depend on the run's seed."""
 
 import numpy as np
 
-from bench import weights
+from bench import spec
+
+DENSE = spec.family("dense_gqa")
 
 
 def _keys(cfg, params, layer, n=256, seed=0):
@@ -22,7 +24,7 @@ def test_heads_have_their_own_maps_inside_one_latent(tiny):
     # four KV heads, so that their joint key features (4 x 8) outnumber
     # the adapter's rank (16), as at the published widths (8 x 32 and 64)
     cfg = dict(tiny("qwen3-8b.longctx-decode").config, num_key_value_heads=4)
-    params = weights.make(cfg)
+    params = DENSE.params(cfg)
     k = _keys(cfg, params, 0)
     # two KV heads read the same tokens differently, but alike
     corr = np.corrcoef(k[:, 0].ravel(), k[:, 1].ravel())[0, 1]
@@ -40,6 +42,6 @@ def test_heads_have_their_own_maps_inside_one_latent(tiny):
 
 def test_weights_come_from_the_configuration_alone(tiny):
     cfg = tiny("qwen3-8b.longctx-decode").config
-    a = weights.make(cfg)["blocks"][1]["attn"]["wk"]
-    b = weights.make(cfg)["blocks"][1]["attn"]["wk"]
+    a = DENSE.params(cfg)["blocks"][1]["attn"]["wk"]
+    b = DENSE.params(cfg)["blocks"][1]["attn"]["wk"]
     assert np.array_equal(np.asarray(a), np.asarray(b))
