@@ -35,6 +35,7 @@ class BlockMeta:
     n_groups: int               # ... in the slab, per layer
     last_used: int              # logical LRU clock tick
     checksum: int = 0           # CRC32 of the extent's at-rest bytes; 0 = unverifiable (pre-checksum manifest)
+    window: bool = False        # the extent holds the windowed layers' K/V too
     pins: int = 0               # runtime refcount; never persisted
 
     def to_json(self) -> dict:

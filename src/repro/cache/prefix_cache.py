@@ -306,6 +306,13 @@ class PrefixCache:
     def contains(self, block_id: str) -> bool:
         return self.manifest is not None and block_id in self.manifest.blocks
 
+    def holds_window(self, block_id: str) -> bool:
+        """Whether a resident block holds its windowed layers' K/V (a
+        block published older than the window holds only the full
+        layers')."""
+        meta = self.manifest.blocks.get(block_id) if self.manifest else None
+        return meta is not None and meta.window
+
     def touch(self, block_id: str) -> None:
         """LRU-refresh a resident block (publish hit) without re-reading KV."""
         meta = self.manifest.blocks.get(block_id) if self.manifest else None
@@ -370,9 +377,12 @@ class PrefixCache:
                 raise RuntimeError(f"unbalanced unpin of block {m.block_id}")
 
     # -- restore ----------------------------------------------------------
-    def read_chain(self, metas: list[BlockMeta]) -> tuple[np.ndarray, np.ndarray]:
+    def read_chain(self, metas: list[BlockMeta], window_layers=(), window: int = 0
+                   ) -> tuple[np.ndarray, np.ndarray]:
         """Read a matched chain's KV: ``(k, v)`` each
-        ``[n_layers, n_tokens, H_kv, d]`` in chain (token) order.
+        ``[n_layers, n_tokens, H_kv, d]`` in chain (token) order.  The
+        ``window_layers`` are read only for the blocks that end inside the
+        chain's last ``window`` tokens, and hold zeros before them.
 
         Reads are planned per layer across *all* matched extents, so chains
         that were published contiguously restore as one long sequential read
@@ -407,12 +417,17 @@ class PrefixCache:
         obs = self._obs
         if obs is not None:
             r0 = obs.tracer.now_wall()
-        k = np.empty((geo.n_layers, n_tok, hkv, d), dtype=geo.np_dtype)
-        v = np.empty_like(k)
+        alloc = np.zeros if window_layers else np.empty
+        k = alloc((geo.n_layers, n_tok, hkv, d), dtype=geo.np_dtype)
+        v = alloc(k.shape, dtype=geo.np_dtype)
+        ends = np.cumsum([m.n_tokens for m in metas])
+        tail = int(np.searchsorted(ends, n_tok - window, side="right")) if window else 0
+        t0 = int(ends[tail - 1]) if tail else 0
         for layer in range(geo.n_layers):
-            kl, vl = self.store.read_extents(layer, extents, self.scheduler)
-            k[layer] = kl.reshape(-1, hkv, d)
-            v[layer] = vl.reshape(-1, hkv, d)
+            first, off = (tail, t0) if layer in window_layers else (0, 0)
+            kl, vl = self.store.read_extents(layer, extents[first:], self.scheduler)
+            k[layer, off:] = kl.reshape(-1, hkv, d)
+            v[layer, off:] = vl.reshape(-1, hkv, d)
         if obs is not None:
             obs.tracer.add(
                 "restore_chain", "prefix-cache", cat="prefix",
@@ -423,11 +438,14 @@ class PrefixCache:
         return k, v
 
     # -- publish ----------------------------------------------------------
-    def put_block(self, block: TokenBlock, k: np.ndarray, v: np.ndarray) -> bool:
-        """Publish one block (``k, v: [n_layers, n_groups, G, H_kv, d]``).
+    def put_block(self, block: TokenBlock, k: np.ndarray, v: np.ndarray,
+                  window: bool = False) -> bool:
+        """Publish one block (``k, v: [n_layers, n_groups, G, H_kv, d]``);
+        ``window`` says whether its windowed layers' K/V are in ``k, v``.
 
         Content addressing makes this idempotent: a resident block is just
-        LRU-touched.  A full slab evicts LRU chains (never pinned ones);
+        LRU-touched (and rewritten if it lacked the windowed layers' K/V
+        that this publish brings).  A full slab evicts LRU chains (never pinned ones);
         returns ``False`` if the budget is entirely pinned and the block was
         declined.  The parent must already be resident (publish chains
         root-first) so resident blocks always form rooted chains.
@@ -435,6 +453,9 @@ class PrefixCache:
         geo = self.manifest.geometry
         existing = self.manifest.blocks.get(block.block_id)
         if existing is not None:
+            if window and not existing.window:
+                existing.checksum = self.store.write_block(existing.start_group, k, v)
+                existing.window = True
             self.manifest.touch(existing)
             self.stats.dedup_blocks += 1
             if self._obs is not None:
@@ -474,7 +495,7 @@ class PrefixCache:
             block_id=block.block_id, parent_id=block.parent_id,
             index=block.index, n_tokens=block.n_tokens,
             start_group=start, n_groups=ng, last_used=self.manifest.tick(),
-            checksum=checksum)
+            checksum=checksum, window=window)
         self.manifest.blocks[meta.block_id] = meta
         self.stats.published_blocks += 1
         if self._obs is not None:

@@ -17,8 +17,10 @@ _MODULES = {
     "xlstm-1.3b": "repro.configs.xlstm_1p3b",
 }
 
-# Bonus (beyond the assigned pool): the paper's second evaluation model.
-_EXTRA = {"qwen3-8b": "repro.configs.qwen3_8b"}
+# Beyond the assigned pool: the paper's second evaluation model, and the
+# served path's windowed/expert model.
+_EXTRA = {"qwen3-8b": "repro.configs.qwen3_8b",
+          "smallthinker-21b-a3b": "repro.configs.smallthinker_21b_a3b"}
 _MODULES = dict(_MODULES, **_EXTRA)
 
 ARCHS = tuple(m for m in _MODULES if m not in _EXTRA)

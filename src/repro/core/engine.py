@@ -340,6 +340,12 @@ def _tail_write(tail: jax.Array, new: jax.Array, fidx: jax.Array,
     return tail.at[rows, fidx].set(upd)
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _ring_row(ring: jax.Array, bi: jax.Array, row: jax.Array) -> jax.Array:
+    """Row ``bi`` of a window ring ``[B, W, H_kv, d]`` replaced by ``row``."""
+    return jax.lax.dynamic_update_index_in_dim(ring, row.astype(ring.dtype), bi, 0)
+
+
 class KVSwapEngine:
     """Serve one batch of sequences with the KVSwap runtime."""
 
@@ -376,10 +382,18 @@ class KVSwapEngine:
         g = cfg.group_size
         self.max_groups = (cfg.max_seq + g - 1) // g
         self.cap_tokens = self.max_groups * g
-        # hybrid support: only "kv" layers own disk-backed KV state
+        # only "kv" layers own disk-backed KV state; "window" layers keep
+        # their last `window` tokens in a device ring, "state" layers a
+        # recurrent state (hybrid support)
         self.layer_kinds = tuple(getattr(model, "layer_kinds", ("kv",) * model.n_layers))
         self.kv_layers = [i for i, k in enumerate(self.layer_kinds) if k == "kv"]
         self._kv_index = {layer: j for j, layer in enumerate(self.kv_layers)}
+        self.window_layers = [i for i, k in enumerate(self.layer_kinds) if k == "window"]
+        self._win_index = {layer: w for w, layer in enumerate(self.window_layers)}
+        self.window = int(model.window) if self.window_layers else 0
+        # the prefix cache holds every attention layer, in model order
+        self.attn_layers = [i for i, k in enumerate(self.layer_kinds) if k != "state"]
+        self._attn_index = {layer: c for c, layer in enumerate(self.attn_layers)}
         n_kv_layers = len(self.kv_layers)
         self.accountant = IOAccountant(cfg.disk_spec)
         if self.obs.enabled:
@@ -474,6 +488,16 @@ class KVSwapEngine:
         self.quality = PrefetchQualityMeter()
         # recurrent state for non-KV (SSM / xLSTM) layers
         self.states: dict[int, object] = {}
+        # windowed layers' rings [B, W, H_kv, d] in the engine dtype: the
+        # token at position p in slot p % W, written in place by decode
+        ring = (batch, self.window, model.n_kv_heads, model.head_dim)
+        self._ring_k = [jnp.zeros(ring, cfg.dtype) for _ in self.window_layers]
+        self._ring_v = [jnp.zeros(ring, cfg.dtype) for _ in self.window_layers]
+        if self.obs.enabled and self.window_layers:
+            self.obs.registry.gauge(
+                "kvswap_window_ring_bytes",
+                "device bytes the windowed layers' rings hold").set(
+                sum(r.nbytes for r in self._ring_k + self._ring_v))
         # Preallocated compressed K cache, one per KV layer: [B, cap_tokens, r]
         self.k_lr = [
             jnp.zeros((batch, self.cap_tokens, cfg.rank), dtype=jnp.float32)
@@ -557,6 +581,9 @@ class KVSwapEngine:
             out["warm_tier_index"] = self.warm.index_nbytes
             out["warm_budget_bytes"] = self.warm.budget_bytes
             out["total"] += out["warm_tier"] + out["warm_tier_index"]
+        if self.window_layers:
+            # device memory, like the mirrors below
+            out["window_ring"] = sum(r.nbytes for r in self._ring_k + self._ring_v)
         if any(r.device is not None for r in self.reuse):
             # the device mirrors double C's footprint (host copy + device
             # mirror); reported separately — it bounds *device* memory
@@ -629,6 +656,58 @@ class KVSwapEngine:
             rows = compress_k(k_dev[:, : ng * g].astype(jnp.float32), self.adapter)
             self.k_lr[j] = _klr_append(self.k_lr[j], rows, jnp.int32(0))
 
+    def _seed_ring(self, layer: int, rows, k: np.ndarray, v: np.ndarray,
+                   end: int) -> None:
+        """Seed windowed ``layer``'s ring rows from the K/V ``[len(rows), n,
+        H_kv, d]`` of the ``n`` tokens before position ``end``: the last
+        ``window`` of them, each in its slot, the other slots cleared."""
+        wl, w = self._win_index[layer], self.window
+        n = min(k.shape[1], w)
+        slots = np.arange(end - n, end) % w
+        for i, bi in enumerate(rows):
+            for ring, src in ((self._ring_k, k), (self._ring_v, v)):
+                row = np.zeros(ring[wl].shape[1:], ring[wl].dtype)
+                row[slots] = src[i, -n:]
+                ring[wl] = _ring_row(ring[wl], jnp.int32(bi), jnp.asarray(row))
+
+    def _window_tail(self, k_pre: np.ndarray, layer: int) -> np.ndarray:
+        """The part of restored prefix K/V ``[..., n, H_kv, d]`` that
+        ``layer`` attends: all of it, or a windowed layer's last ``window``
+        tokens (the only ones a restore holds for it)."""
+        return k_pre[..., -self.window:, :, :] if layer in self._win_index else k_pre
+
+    def _window_covered(self, chain, n_blocks: int) -> bool:
+        """Whether a restore of the chain's first ``n_blocks`` may seed the
+        rings: every block ending within ``window`` tokens of their end
+        holds its windowed layers' K/V."""
+        if not self.window_layers or not n_blocks:
+            return True
+        ends = np.cumsum([m.n_tokens for m in chain[:n_blocks]])
+        return all(m.window for m, e in zip(chain, ends) if e > ends[-1] - self.window)
+
+    def _common_chains(self, cache, tokens_np: np.ndarray, s: int) -> list:
+        """Each row's cached chain, cut to the longest prefix every row
+        matches whose windowed blocks cover the window before its end (a
+        restore that cannot seed the rings falls back to a shorter one)."""
+        chains = [cache.match(t, max_tokens=s - 1) for t in tokens_np]
+        n = min(len(ch) for ch in chains)
+        while not all(self._window_covered(ch, n) for ch in chains):
+            n -= 1
+        return [ch[:n] for ch in chains]
+
+    def _restore_read(self, cache, chain):
+        """``cache.read_chain`` of a window-covered chain: the windowed
+        layers' K/V only for its last ``window`` tokens."""
+        return cache.read_chain(chain, [self._attn_index[i] for i in self.window_layers],
+                                self.window)
+
+    def _open_cache(self, cache) -> None:
+        cache.open(n_layers=len(self.attn_layers), group_size=self.cfg.group_size,
+                   n_kv_heads=self.model.n_kv_heads,
+                   head_dim=self.model.head_dim, dtype=self.cfg.np_dtype)
+        cache.use_accountant(self.accountant)
+        cache.use_obs(self.obs)
+
     def prefill(self, tokens: np.ndarray) -> jax.Array:
         """Run full-attention prefill, spill KV to disk layer-by-layer, build
         the compressed K cache.  Returns last-position logits ``[B, V]``."""
@@ -650,11 +729,13 @@ class KVSwapEngine:
                     x, st = self.model.prefill_state_block(self.params, layer, x, positions)
                     self.states[layer] = st
                     continue
-                j = self._kv_index[layer]
                 x, k, v = self.model.prefill_block(self.params, layer, x, positions)
                 k_np = np.asarray(jax.device_get(k), dtype=self.cfg.np_dtype)
                 v_np = np.asarray(jax.device_get(v), dtype=self.cfg.np_dtype)
-                self._spill_prefill_layer(j, k_np, v_np, k, s)
+                if layer in self._win_index:
+                    self._seed_ring(layer, range(b), k_np, v_np, s)
+                else:
+                    self._spill_prefill_layer(self._kv_index[layer], k_np, v_np, k, s)
         self.row_valid[:] = (s // g) * g
         self.row_seq[:] = s
         self.row_active[:] = True
@@ -690,7 +771,8 @@ class KVSwapEngine:
         cached prefix (minimum over rows) — the intended workload is batched
         requests sharing a system prompt / conversation head.  Hybrid models
         fall back to cold prefill: recurrent state lives outside the KV
-        cache.
+        cache.  Windowed layers restore only their last ``window`` tokens,
+        which is all their suffix attends and all their rings hold.
         """
         t0 = time.perf_counter()
         tokens_np = np.asarray(jax.device_get(tokens))
@@ -700,21 +782,15 @@ class KVSwapEngine:
         # cold fallbacks: hybrid models keep recurrent state outside the KV
         # cache, and adapters predating the chunked-prefill protocol can
         # still publish/serve cold
-        if (any(kind != "kv" for kind in self.layer_kinds)
+        if ("state" in self.layer_kinds
                 or not hasattr(self.model, "prefill_block_with_ctx")):
             return self.prefill(tokens_np)
         g = self.cfg.group_size
-        cache.open(n_layers=len(self.kv_layers), group_size=g,
-                   n_kv_heads=self.model.n_kv_heads,
-                   head_dim=self.model.head_dim, dtype=self.cfg.np_dtype)
-        cache.use_accountant(self.accountant)
-        cache.use_obs(self.obs)
-        chains = [cache.match(tokens_np[bi], max_tokens=s - 1) for bi in range(b)]
-        n_cached = min(sum(m.n_tokens for m in ch) for ch in chains)
+        self._open_cache(cache)
+        chains = self._common_chains(cache, tokens_np, s)
+        n_cached = sum(m.n_tokens for m in chains[0])
         if n_cached == 0:
             return self.prefill(tokens_np)
-        n_blocks = n_cached // cache.cfg.block_tokens
-        chains = [ch[:n_blocks] for ch in chains]
         self._reset_device_state()   # mirrors rebuilt at first decode
         for bi in range(self.batch):   # lockstep admission of every slot
             self._free_row(bi)
@@ -730,21 +806,18 @@ class KVSwapEngine:
                 for ch in uniq.values():
                     cache.pin(ch)
                 try:
-                    data = {key: cache.read_chain(ch) for key, ch in uniq.items()}
+                    data = {key: self._restore_read(cache, ch) for key, ch in uniq.items()}
                     break
                 except CorruptBlockError:
-                    chains = [cache.match(tokens_np[bi], max_tokens=s - 1)
-                              for bi in range(b)]
-                    n_cached = min(sum(m.n_tokens for m in ch) for ch in chains)
+                    chains = self._common_chains(cache, tokens_np, s)
+                    n_cached = sum(m.n_tokens for m in chains[0])
                     if n_cached == 0:
                         return self.prefill(tokens_np)
-                    n_blocks = n_cached // cache.cfg.block_tokens
-                    chains = [ch[:n_blocks] for ch in chains]
                 finally:
                     for ch in uniq.values():
                         cache.unpin(ch)
-            nkv, hkv, hd = len(self.kv_layers), self.model.n_kv_heads, self.model.head_dim
-            k_pre = np.empty((nkv, b, n_cached, hkv, hd), dtype=self.cfg.np_dtype)
+            nl, hkv, hd = len(self.attn_layers), self.model.n_kv_heads, self.model.head_dim
+            k_pre = np.empty((nl, b, n_cached, hkv, hd), dtype=self.cfg.np_dtype)
             v_pre = np.empty_like(k_pre)
             for bi, ch in enumerate(chains):
                 k_pre[:, bi], v_pre[:, bi] = data[ch[-1].block_id]
@@ -753,17 +826,22 @@ class KVSwapEngine:
             x = self.model.embed(self.params, jnp.asarray(tokens_np[:, n_cached:]))
             ng = s // g
             for layer in range(self.model.n_layers):
-                j = self._kv_index[layer]
-                kp = jnp.asarray(k_pre[j])
-                vp = jnp.asarray(v_pre[j])
+                c = self._attn_index[layer]
+                kp_np = self._window_tail(k_pre[c], layer)
+                vp_np = self._window_tail(v_pre[c], layer)
+                kp, vp = jnp.asarray(kp_np), jnp.asarray(vp_np)
                 x, k_suf, v_suf = self.model.prefill_block_with_ctx(
                     self.params, layer, x, positions, kp, vp)
                 k_np = np.concatenate(
-                    [k_pre[j], np.asarray(jax.device_get(k_suf), dtype=self.cfg.np_dtype)], axis=1)
+                    [kp_np, np.asarray(jax.device_get(k_suf), dtype=self.cfg.np_dtype)], axis=1)
                 v_np = np.concatenate(
-                    [v_pre[j], np.asarray(jax.device_get(v_suf), dtype=self.cfg.np_dtype)], axis=1)
+                    [vp_np, np.asarray(jax.device_get(v_suf), dtype=self.cfg.np_dtype)], axis=1)
+                if layer in self._win_index:
+                    self._seed_ring(layer, range(b), k_np, v_np, s)
+                    continue
                 self._spill_prefill_layer(
-                    j, k_np, v_np, jnp.concatenate([kp, k_suf], axis=1), s)
+                    self._kv_index[layer], k_np, v_np,
+                    jnp.concatenate([kp, k_suf], axis=1), s)
         self.row_valid[:] = ng * g
         self.row_seq[:] = s
         self.row_active[:] = True
@@ -781,13 +859,13 @@ class KVSwapEngine:
         Returns ``(n_cached, k_pre, v_pre)`` — ``(0, None, None)`` when
         nothing restorable is left."""
         while True:
-            chain = cache.match(tokens_np, max_tokens=s - 1)
+            chain = self._common_chains(cache, tokens_np[None], s)[0]
             n_cached = sum(m.n_tokens for m in chain)
             if not n_cached:
                 return 0, None, None
             cache.pin(chain)
             try:
-                k_pre, v_pre = cache.read_chain(chain)  # [nkv, n_cached, hkv, d]
+                k_pre, v_pre = self._restore_read(cache, chain)  # [layers, n_cached, hkv, d]
                 return n_cached, k_pre, v_pre
             except CorruptBlockError:
                 continue
@@ -815,7 +893,7 @@ class KVSwapEngine:
         """
         if self.row_active[bi]:
             raise RuntimeError(f"slot {bi} is busy; retire it first")
-        if any(kind != "kv" for kind in self.layer_kinds):
+        if "state" in self.layer_kinds:
             raise ValueError("admit_row requires attention-only models "
                              "(recurrent state has no per-row lifecycle)")
         tokens_np = np.asarray(jax.device_get(tokens)).reshape(-1).astype(np.int64)
@@ -828,7 +906,6 @@ class KVSwapEngine:
         self._free_row(bi)
         g = self.cfg.group_size
         ng = s // g
-        nkv = len(self.kv_layers)
         warm = (cache is not None
                 and hasattr(self.model, "prefill_block_with_ctx"))
         n_cached = 0
@@ -836,36 +913,36 @@ class KVSwapEngine:
         try:
             with self.accountant.track() as tr:
                 if warm:
-                    cache.open(n_layers=nkv, group_size=g,
-                               n_kv_heads=self.model.n_kv_heads,
-                               head_dim=self.model.head_dim,
-                               dtype=self.cfg.np_dtype)
-                    cache.use_accountant(self.accountant)
-                    cache.use_obs(self.obs)
+                    self._open_cache(cache)
                     n_cached, k_pre, v_pre = self._restore_prefix(
                         cache, tokens_np, s)
                 positions = jnp.arange(n_cached, s)[None, :]
                 x = self.model.embed(
                     self.params, jnp.asarray(tokens_np[None, n_cached:]))
                 for layer in range(self.model.n_layers):
-                    j = self._kv_index[layer]
                     if n_cached:
-                        kp = jnp.asarray(k_pre[j][None])
-                        vp = jnp.asarray(v_pre[j][None])
+                        c = self._attn_index[layer]
+                        kp_np = self._window_tail(k_pre[c], layer)
+                        vp_np = self._window_tail(v_pre[c], layer)
+                        kp, vp = jnp.asarray(kp_np[None]), jnp.asarray(vp_np[None])
                         x, k_suf, v_suf = self.model.prefill_block_with_ctx(
                             self.params, layer, x, positions, kp, vp)
                         k_dev = jnp.concatenate([kp, k_suf], axis=1)
                         k_np = np.concatenate(
-                            [k_pre[j], np.asarray(jax.device_get(k_suf[0]),
-                                                  dtype=self.cfg.np_dtype)], axis=0)
+                            [kp_np, np.asarray(jax.device_get(k_suf[0]),
+                                               dtype=self.cfg.np_dtype)], axis=0)
                         v_np = np.concatenate(
-                            [v_pre[j], np.asarray(jax.device_get(v_suf[0]),
-                                                  dtype=self.cfg.np_dtype)], axis=0)
+                            [vp_np, np.asarray(jax.device_get(v_suf[0]),
+                                               dtype=self.cfg.np_dtype)], axis=0)
                     else:
                         x, k, v = self.model.prefill_block(self.params, layer, x, positions)
                         k_dev = k
                         k_np = np.asarray(jax.device_get(k[0]), dtype=self.cfg.np_dtype)
                         v_np = np.asarray(jax.device_get(v[0]), dtype=self.cfg.np_dtype)
+                    if layer in self._win_index:
+                        self._seed_ring(layer, [bi], k_np[None], v_np[None], s)
+                        continue
+                    j = self._kv_index[layer]
                     self.store.write_prefill_row(j, bi, k_np, v_np)
                     if s - ng * g:
                         self.rolling[j].seed_row(bi, k_np[ng * g:], v_np[ng * g:])
@@ -952,6 +1029,10 @@ class KVSwapEngine:
         for j in range(len(self.kv_layers)):
             self.managers[j].free_row(bi)
         self.store.free_row(bi)
+        for rings in (self._ring_k, self._ring_v):
+            for wl, ring in enumerate(rings):
+                rings[wl] = _ring_row(ring, jnp.int32(bi),
+                                      jnp.zeros(ring.shape[1:], ring.dtype))
         # forget the slot's selection history: a recycled slot's first step
         # must not score against the previous tenant's selections
         self.quality.clear_row(bi)
@@ -979,21 +1060,21 @@ class KVSwapEngine:
         prompt).  ``save=False`` defers the manifest write — per-request
         publishers (the serving session retires rows one at a time) save
         once at drain instead of rewriting the manifest per retirement.
+
+        Windowed layers' K/V come from the rings, which hold each row's last
+        ``window`` tokens: a block published older than that carries only
+        the full layers' K/V (``window=False`` in the cache), and a resident
+        block that lacked them is rewritten when a publish has them.
         """
-        if any(kind != "kv" for kind in self.layer_kinds):
+        if "state" in self.layer_kinds:
             return PublishResult(0, {})
         if tokens is None:
             tokens = self._prompt_np
         if tokens is None:        # nothing prefilled yet → nothing to publish
             return PublishResult(0, {})
         g = self.cfg.group_size
-        cache.open(n_layers=len(self.kv_layers), group_size=g,
-                   n_kv_heads=self.model.n_kv_heads,
-                   head_dim=self.model.head_dim, dtype=self.cfg.np_dtype)
-        cache.use_accountant(self.accountant)
-        cache.use_obs(self.obs)
+        self._open_cache(cache)
         bt = cache.cfg.block_tokens
-        nkv = len(self.kv_layers)
         hkv, hd = self.model.n_kv_heads, self.model.head_dim
         published = 0
         heads: dict[int, str | None] = {}
@@ -1003,12 +1084,15 @@ class KVSwapEngine:
             on_disk = int(self.store.n_groups[:, bi].min()) * g
             usable = min(len(toks), on_disk)
             chain = chain_blocks(toks[:usable], bt)
+            # first token whose windowed K/V the rings still hold
+            held = int(self.row_seq[bi]) - self.window if self.window_layers else usable
             # resident blocks form rooted chains, so the missing blocks are
             # a contiguous suffix: touch the resident prefix, then read the
             # whole missing range as ONE sequential run per layer
             n_res = 0
             for blk in chain:
-                if not cache.contains(blk.block_id):
+                if not cache.contains(blk.block_id) or (
+                        blk.index * bt >= held and not cache.holds_window(blk.block_id)):
                     break
                 cache.touch(blk.block_id)
                 n_res += 1
@@ -1017,18 +1101,29 @@ class KVSwapEngine:
             if missing:
                 g0 = missing[0].index * bg
                 ngr = len(missing) * bg
-                k = np.empty((nkv, ngr, g, hkv, hd), dtype=self.cfg.np_dtype)
-                v = np.empty_like(k)
-                for j in range(nkv):
+                k = np.zeros((len(self.attn_layers), ngr, g, hkv, hd), dtype=self.cfg.np_dtype)
+                v = np.zeros_like(k)
+                for j, layer in enumerate(self.kv_layers):
                     # retried like a decode fetch: a transient read error must
                     # not fail the request at the finish line (publishing is
                     # best-effort, but a retry is cheaper than losing the chain)
-                    k[j], v[j] = self.managers[j].read_run_with_retry(
+                    c = self._attn_index[layer]
+                    k[c], v[c] = self.managers[j].read_run_with_retry(
                         bi, ReadRun(g0, ngr, tuple(range(g0, g0 + ngr))))
+                if self.window_layers:
+                    pos = g0 * g + np.arange(ngr * g)
+                    ok = pos >= held
+                    ring_k, ring_v = jax.device_get(
+                        ([r[bi] for r in self._ring_k], [r[bi] for r in self._ring_v]))
+                    for layer, rk, rv in zip(self.window_layers, ring_k, ring_v):
+                        c = self._attn_index[layer]
+                        for dst, ring in ((k[c], rk), (v[c], rv)):
+                            flat = dst.reshape(ngr * g, hkv, hd)
+                            flat[ok] = ring[pos[ok] % self.window]
                 for blk in missing:
                     off = (blk.index * bg) - g0
-                    if not cache.put_block(blk, k[:, off:off + bg],
-                                           v[:, off:off + bg]):
+                    if not cache.put_block(blk, k[:, off:off + bg], v[:, off:off + bg],
+                                           window=blk.index * bt >= held):
                         break   # budget exhausted by pinned blocks; keep the chain rooted
                     published += 1
                     n_ok += 1
@@ -1252,6 +1347,21 @@ class KVSwapEngine:
         )
         return x
 
+    def _window_layer(self, layer: int, x: jax.Array, pos: jax.Array,
+                      t_compute: list[float]) -> jax.Array:
+        """A windowed layer: one dispatch over its device ring, which takes
+        the token's K/V in place; nothing reaches the host or the disk."""
+        wl = self._win_index[layer]
+        with self.obs.phase("window", layer=layer) as ph:
+            x, self._ring_k[wl], self._ring_v[wl] = self.model.decode_window_block(
+                self.params, layer, x, pos, self._ring_k[wl], self._ring_v[wl],
+                jnp.asarray(self._step_active))
+            t_compute.append(hardware.decode_layer_time(
+                self.compute_spec, self.dims, n_ctx=self.window,
+                batch=int(self._step_active.sum())))
+            ph.note(modeled_compute_s=t_compute[-1])
+        return x
+
     def _kv_layer(self, layer: int, j: int, x: jax.Array, pos: jax.Array, table,
                   t_compute: list[float], done: np.ndarray) -> jax.Array:
         if self.device_resident:
@@ -1359,9 +1469,11 @@ class KVSwapEngine:
         io_wait = 0.0
         x_prev = x
         for layer in range(self.model.n_layers):
-            if self.layer_kinds[layer] == "state":
+            kind = self.layer_kinds[layer]
+            if kind != "kv":
                 x_prev = x
-                x = self._state_layer(layer, x, pos, t_compute)
+                x = (self._state_layer if kind == "state" else self._window_layer)(
+                    layer, x, pos, t_compute)
                 t_io.append(0.0)
                 continue
             j = self._kv_index[layer]
@@ -1405,8 +1517,10 @@ class KVSwapEngine:
                     jj = self._kv_index[L]
                     ids, mask = self._predict_for(L, jj, x, pos, valid)
                     buf.stage(jj, self.prefetcher.submit(jj, ids, mask))
-                if self.layer_kinds[layer] == "state":
-                    x = self._state_layer(layer, x, pos, t_compute)
+                kind = self.layer_kinds[layer]
+                if kind != "kv":
+                    x = (self._state_layer if kind == "state" else self._window_layer)(
+                        layer, x, pos, t_compute)
                     t_io.append(0.0)
                     continue
                 j = self._kv_index[layer]

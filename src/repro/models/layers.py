@@ -90,8 +90,10 @@ def init_attention(key, *, d_model: int, n_heads: int, n_kv_heads: int, head_dim
 
 
 def attention_qkv(p, x: jax.Array, positions: jax.Array, *, n_heads: int,
-                  n_kv_heads: int, head_dim: int, rope_theta: float, qk_norm: bool):
-    """Project + (qk-)norm + RoPE.  ``x: [B, S, D]`` → q [B,S,H,d], k/v [B,S,Hk,d]."""
+                  n_kv_heads: int, head_dim: int, rope_theta: float, qk_norm: bool,
+                  rope: bool = True):
+    """Project + (qk-)norm + RoPE (none where ``rope`` is off: a NoPE layer).
+    ``x: [B, S, D]`` → q [B,S,H,d], k/v [B,S,Hk,d]."""
     b, s, _ = x.shape
     q = (x @ p["wq"]).reshape(b, s, n_heads, head_dim)
     k = (x @ p["wk"]).reshape(b, s, n_kv_heads, head_dim)
@@ -99,8 +101,9 @@ def attention_qkv(p, x: jax.Array, positions: jax.Array, *, n_heads: int,
     if qk_norm:
         q = rmsnorm(p["q_norm"], q)
         k = rmsnorm(p["k_norm"], k)
-    q = apply_rope(q, positions, rope_theta)
-    k = apply_rope(k, positions, rope_theta)
+    if rope:
+        q = apply_rope(q, positions, rope_theta)
+        k = apply_rope(k, positions, rope_theta)
     return q, k, v
 
 
@@ -111,26 +114,40 @@ def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
     return jnp.repeat(x, n_rep, axis=-2)
 
 
-def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
-    """Full causal attention.  q [B,S,H,d], k/v [B,S,Hk,d] → [B,S,H,d]."""
+def _window_mask(mask: jax.Array, q_pos: jax.Array, k_pos: jax.Array,
+                 window: int) -> jax.Array:
+    """``mask`` narrowed to the ``window`` keys up to and including each
+    query's own position (0: no window)."""
+    if not window:
+        return mask
+    return mask & (q_pos[:, None] - k_pos[None, :] < window)
+
+
+def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     window: int = 0) -> jax.Array:
+    """Causal attention, over the last ``window`` keys where ``window`` is
+    set.  q [B,S,H,d], k/v [B,S,Hk,d] → [B,S,H,d]."""
     b, s, h, d = q.shape
     hk = k.shape[2]
     kq = repeat_kv(k, h // hk)
     vq = repeat_kv(v, h // hk)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, kq) / jnp.sqrt(d).astype(q.dtype)
     mask = jnp.tril(jnp.ones((s, s), bool))
+    mask = _window_mask(mask, jnp.arange(s), jnp.arange(s), window)
     scores = jnp.where(mask[None, None], scores, jnp.finfo(scores.dtype).min)
     w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", w, vq)
 
 
 def chunked_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                             q_start: int) -> jax.Array:
+                             q_start: int, window: int = 0) -> jax.Array:
     """Causal attention for a *suffix chunk* of queries over the full keys.
 
-    ``q [B,Sq,H,d]`` covers absolute positions ``[q_start, q_start+Sq)``;
-    ``k/v [B,Sk,Hk,d]`` cover positions ``[0, Sk)`` (cached prefix KV
-    concatenated with the chunk's own KV).  With ``q_start=0`` and
+    ``q [B,Sq,H,d]`` covers positions ``[q_start, q_start+Sq)`` and
+    ``k/v [B,Sk,Hk,d]`` positions ``[0, Sk)`` (cached prefix KV
+    concatenated with the chunk's own KV), both counted from the first key:
+    a windowed layer's prefix may hold only its last keys.  ``window``
+    narrows each query to the last ``window`` keys.  With ``q_start=0`` and
     ``Sq == Sk`` this is exactly :func:`causal_attention` — the chunked path
     computes the same score rows, so restoring bit-identical prefix KV makes
     warm prefill bit-identical to cold (see ``KVSwapEngine.prefill_cached``).
@@ -143,7 +160,8 @@ def chunked_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, kq) / jnp.sqrt(d).astype(q.dtype)
     # the [Sq, Sk] slice of the causal mask, built directly (an Sk×Sk tril
     # would be quadratic in the cached context for a tiny suffix)
-    mask = (q_start + jnp.arange(sq))[:, None] >= jnp.arange(sk)[None, :]
+    q_pos, k_pos = q_start + jnp.arange(sq), jnp.arange(sk)
+    mask = _window_mask(q_pos[:, None] >= k_pos[None, :], q_pos, k_pos, window)
     scores = jnp.where(mask[None, None], scores, jnp.finfo(scores.dtype).min)
     w = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", w, vq)
@@ -302,24 +320,53 @@ def init_moe(key, *, d_model: int, d_ff: int, n_experts: int, dtype=jnp.float32,
     return p
 
 
-def moe(p, x: jax.Array, *, top_k: int, capacity_factor: float = 1.25):
-    """Top-k routed MoE with capacity-bounded scatter dispatch.
+def moe(p, x: jax.Array, *, top_k: int, capacity_factor: float | None = 1.25,
+        act=jax.nn.silu, logits: jax.Array | None = None):
+    """Top-k routed MoE.  ``x: [B, S, D]`` (or one decode token per row,
+    ``[B, D]``) → ``(y, aux_loss scalar)``, ``y`` shaped as ``x``.
 
-    ``x: [B, S, D]`` → ``(y [B, S, D], aux_loss scalar)``.
+    The router's ``logits`` default to ``x @ router``; a model whose router
+    reads another input (before attention) passes them in.  The top-k
+    gates are renormalised, and each expert is ``W_down(act(x W_gate) ⊙
+    x W_up)``.
 
-    Tokens are scattered into a per-expert buffer ``[B, E, C, D]`` (positions
-    past capacity are dropped), the expert SwiGLU runs batched over the ``E``
-    axis (which is what gets sharded expert-parallel), and outputs gather
-    back.  Memory is O(B·(E·C + S·K)·D) — no dense ``[B,S,E,C]`` one-hots —
-    and compute scales with ``top_k``, not ``n_experts``.
+    With a ``capacity_factor``, tokens are scattered into a per-expert
+    buffer ``[B, E, C, D]`` (positions past capacity are dropped), the
+    experts run batched over the ``E`` axis (which is what gets sharded
+    expert-parallel), and outputs gather back.  Memory is
+    O(B·(E·C + S·K)·D) — no dense ``[B,S,E,C]`` one-hots — and compute
+    scales with ``top_k``, not ``n_experts``.  ``capacity_factor=None`` is
+    dropless: every assignment is computed (:func:`_moe_dropless`).
     """
-    b, s, d = x.shape
-    e = p["router"].shape[1]
-    logits = x @ p["router"]                               # [B,S,E]
+    if logits is None:
+        logits = x @ p["router"]
+    e = logits.shape[-1]
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)      # [B,S,K]
+    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)      # [..., K]
     gate_vals = gate_vals / (gate_vals.sum(axis=-1, keepdims=True) + 1e-9)
+    if capacity_factor is None:
+        y = _moe_dropless(p, x, gate_vals, gate_idx, act)
+    else:
+        y = _moe_capacity(p, x, gate_vals, gate_idx, act, capacity_factor)
+    if "shared" in p:
+        y = y + swiglu(p["shared"], x)
 
+    # Switch-style load-balance loss
+    me = probs.reshape(-1, e).mean(axis=0)                             # [E]
+    ce = jax.nn.one_hot(gate_idx, e).sum(axis=-2).reshape(-1, e).mean(axis=0)  # routed frac
+    aux = e * jnp.sum(me * ce)
+    return y, aux
+
+
+def _moe_capacity(p, x, gate_vals, gate_idx, act, capacity_factor):
+    """Capacity-bounded scatter dispatch (see :func:`moe`); a ``[B, D]``
+    decode batch routes as ``[B, 1, D]``."""
+    shape = x.shape
+    if x.ndim == 2:
+        x, gate_vals, gate_idx = x[:, None], gate_vals[:, None], gate_idx[:, None]
+    b, s, d = x.shape
+    e = p["w_gate"].shape[0]
+    top_k = gate_idx.shape[-1]
     cap = max(1, int(np.ceil(s * top_k / e * capacity_factor)))
     t = s * top_k                                          # assignments per row
     expert_of = gate_idx.reshape(b, t)                     # [B,T]
@@ -339,7 +386,7 @@ def moe(p, x: jax.Array, *, top_k: int, capacity_factor: float = 1.25):
     buf = buf.at[bidx, expert_of, pos_safe].set(x_tok, mode="drop")
     buf = _moe_constrain("buf", buf)          # [B(data), E(model), C, D]
 
-    h = jax.nn.silu(jnp.einsum("becd,edf->becf", buf, p["w_gate"]))
+    h = act(jnp.einsum("becd,edf->becf", buf, p["w_gate"]))
     h = h * jnp.einsum("becd,edf->becf", buf, p["w_up"])
     out = jnp.einsum("becf,efd->becd", h, p["w_down"])     # [B,E,C,D]
     out = _moe_constrain("buf", out)
@@ -347,13 +394,28 @@ def moe(p, x: jax.Array, *, top_k: int, capacity_factor: float = 1.25):
     y_tok = out[bidx, expert_of, pos_safe.clip(0, cap - 1)]            # [B,T,D]
     y_tok = y_tok * (gates * keep)[..., None]
     y = jnp.zeros_like(x).at[bidx, token_of].add(y_tok)
-    y = _moe_constrain("y", y)
+    return _moe_constrain("y", y).reshape(shape)
 
-    if "shared" in p:
-        y = y + swiglu(p["shared"], x)
 
-    # Switch-style load-balance loss
-    me = probs.mean(axis=(0, 1))                                       # [E]
-    ce = jax.nn.one_hot(gate_idx, e).sum(axis=2).mean(axis=(0, 1))     # routed frac
-    aux = e * jnp.sum(me * ce)
-    return y, aux
+def _moe_dropless(p, x, gate_vals, gate_idx, act):
+    """Every top-k assignment computed, whatever the routing: the
+    assignments sorted by expert, and each expert matrix applied to its own
+    run of rows by one grouped matmul (``jax.lax.ragged_dot``, a grouped
+    kernel on the TPU), so only the experts routed to are read.  The gated
+    sum is taken in float32."""
+    d = x.shape[-1]
+    e = p["w_gate"].shape[0]
+    top_k = gate_idx.shape[-1]
+    xt = x.reshape(-1, d)
+    expert_of = gate_idx.reshape(-1)                       # [N·K]
+    order = jnp.argsort(expert_of)
+    token_of = order // top_k
+    sizes = jnp.bincount(expert_of, length=e).astype(jnp.int32)
+    xs = xt[token_of]
+    h = act(jax.lax.ragged_dot(xs, p["w_gate"], sizes)) \
+        * jax.lax.ragged_dot(xs, p["w_up"], sizes)
+    out = jax.lax.ragged_dot(h, p["w_down"], sizes)       # [N·K, D]
+    gates = gate_vals.reshape(-1)[order]
+    y = jnp.zeros(xt.shape, jnp.float32).at[token_of].add(
+        out.astype(jnp.float32) * gates[:, None])
+    return y.astype(x.dtype).reshape(x.shape)

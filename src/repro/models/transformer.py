@@ -56,7 +56,12 @@ class ModelConfig:
     moe_top_k: int = 0
     moe_d_ff: int = 0
     moe_shared_d_ff: int = 0
-    moe_capacity_factor: float = 1.25
+    moe_capacity_factor: float | None = 1.25   # None: dropless (every assignment)
+    moe_act: str = "silu"          # expert gate: "silu" (SwiGLU) | "relu" (ReGLU)
+    router_before_attn: bool = False   # router reads the layer's normed input
+    sliding_window: int = 0        # keys a windowed layer's query sees, its own included
+    window_layers: tuple = ()      # layers that attend only the window
+    nope_layers: tuple = ()        # layers without rotary embedding
     ssm_state: int = 0
     ssm_expand: int = 2
     ssm_use_pallas: bool = False   # route Mamba2 intra-chunk through Pallas
@@ -70,6 +75,10 @@ class ModelConfig:
     @property
     def kv_layers(self) -> tuple:
         return tuple(i for i, k in enumerate(self.blocks) if k in ATTN_KINDS)
+
+    def window(self, layer: int) -> int:
+        """Keys ``layer``'s query sees, its own included (0: every earlier one)."""
+        return self.sliding_window if layer in self.window_layers else 0
 
     def param_count(self) -> int:
         """Analytic parameter count (for 6·N·D roofline bookkeeping)."""
@@ -189,6 +198,36 @@ def _attn_params(params, cfg: ModelConfig, layer: int):
     return blk, blk["attn"], blk
 
 
+def _qkv(params, cfg: ModelConfig, layer: int, h, positions):
+    """q, k, v of the normed layer input ``h [B, S, D]`` (rotated unless the
+    layer is NoPE), and the router's logits where the router reads ``h``."""
+    _, attn_p, _ = _attn_params(params, cfg, layer)
+    q, k, v = L.attention_qkv(attn_p, h, positions, n_heads=cfg.n_heads,
+                              n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+                              rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
+                              rope=layer not in cfg.nope_layers)
+    r = None
+    if cfg.blocks[layer] == "moe_attn" and cfg.router_before_attn:
+        r = h @ params["blocks"][layer]["moe"]["router"]
+    return q, k, v, r
+
+
+def _finish(params, cfg: ModelConfig, layer: int, x, o, r):
+    """The block after attention: ``x`` plus the output projection of ``o``,
+    then plus the feed-forward sublayer of the normed stream (routed by
+    ``r`` where the router read the layer's input).  Returns (x, aux)."""
+    _, attn_p, holder = _attn_params(params, cfg, layer)
+    x = x + o.reshape(*x.shape[:-1], cfg.n_heads * cfg.head_dim) @ attn_p["wo"]
+    h2 = L.rmsnorm(holder["mlp_norm"], x)
+    if cfg.blocks[layer] == "moe_attn":
+        y, aux = L.moe(params["blocks"][layer]["moe"], h2, top_k=cfg.moe_top_k,
+                       capacity_factor=cfg.moe_capacity_factor,
+                       act=getattr(jax.nn, cfg.moe_act), logits=r)
+    else:
+        y, aux = L.swiglu(holder["mlp"], h2), 0.0
+    return x + y, aux
+
+
 def block_forward(params, cfg: ModelConfig, layer: int, x, positions, state=None,
                   *, return_kv: bool = False):
     """Full-seq forward through one block.  Returns (x, aux, kv_or_state)."""
@@ -196,20 +235,12 @@ def block_forward(params, cfg: ModelConfig, layer: int, x, positions, state=None
     blk = params["blocks"][layer]
     aux = 0.0
     if kind in ATTN_KINDS:
-        nb, attn_p, mlp_holder = _attn_params(params, cfg, layer)
+        nb, _, _ = _attn_params(params, cfg, layer)
         h = L.rmsnorm(nb["attn_norm"], x)
-        q, k, v = L.attention_qkv(attn_p, h, positions, n_heads=cfg.n_heads,
-                                  n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
-                                  rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm)
-        o = L.causal_attention(q, k, v)
-        x = x + o.reshape(*x.shape[:-1], cfg.n_heads * cfg.head_dim) @ attn_p["wo"]
-        h2 = L.rmsnorm(mlp_holder["mlp_norm"], x)
-        if kind == "moe_attn":
-            y, aux = L.moe(blk["moe"], h2, top_k=cfg.moe_top_k,
-                           capacity_factor=cfg.moe_capacity_factor)
-        else:
-            y = L.swiglu(mlp_holder["mlp"], h2)
-        x = _act_constrain(x + y)
+        q, k, v, r = _qkv(params, cfg, layer, h, positions)
+        o = L.causal_attention(q, k, v, cfg.window(layer))
+        x, aux = _finish(params, cfg, layer, x, o, r)
+        x = _act_constrain(x)
         return x, aux, ((k, v) if return_kv else None)
     # state blocks
     h = L.rmsnorm(blk["norm"], x)
@@ -260,9 +291,11 @@ def forward(params, cfg: ModelConfig, tokens, *, embeddings=None,
 class TransformerAdapter:
     """Implements repro.core.adapter.ModelAdapter for this stack.
 
-    ``n_layers`` as seen by the engine counts **all** blocks; blocks whose
-    kind is a state kind expose ``layer_kinds`` so the engine can route them
-    through the stateful path (hybrid support).
+    ``n_layers`` as seen by the engine counts **all** blocks, and
+    ``layer_kinds`` tells the engine how to serve each: ``"kv"`` (attention
+    over every earlier token: KVSwap's disk-backed cache), ``"window"``
+    (attention over the last ``window`` tokens, held in a device ring) or
+    ``"state"`` (recurrent state: hybrid support).
     """
 
     def __init__(self, cfg: ModelConfig):
@@ -274,7 +307,10 @@ class TransformerAdapter:
         self.d_model = cfg.d_model
         self.d_ff = cfg.d_ff or 4 * cfg.d_model
         self.vocab_size = cfg.vocab_size
-        self.layer_kinds = tuple("kv" if k in ATTN_KINDS else "state" for k in cfg.blocks)
+        self.window = cfg.sliding_window
+        self.layer_kinds = tuple(
+            "state" if k not in ATTN_KINDS else "window" if cfg.window(i) else "kv"
+            for i, k in enumerate(cfg.blocks))
 
     # -- embedding / head -------------------------------------------------
     def embed(self, params, tokens):
@@ -299,7 +335,8 @@ class TransformerAdapter:
         ``layer``, attending over restored prefix KV plus their own.
 
         ``x [B, S_suf, D]``, ``positions [B, S_suf]`` (absolute),
-        ``k_prefix/v_prefix [B, S_pre, H_kv, d]`` (post-RoPE, as cached).
+        ``k_prefix/v_prefix [B, S_pre, H_kv, d]`` (post-RoPE, as cached;
+        a windowed layer needs only the last ``window`` of them).
         Returns ``(x_out [B, S_suf, D], k_suf, v_suf [B, S_suf, H_kv, d])``.
 
         Deliberately NOT jitted as a whole block: :func:`block_forward` (the
@@ -310,24 +347,15 @@ class TransformerAdapter:
         which matches the full forward exactly when no tokens are dropped).
         """
         cfg = self.cfg
-        kind = cfg.blocks[layer]
-        blk = params["blocks"][layer]
-        nb, attn_p, mlp_holder = _attn_params(params, cfg, layer)
+        nb, _, _ = _attn_params(params, cfg, layer)
         h = L.rmsnorm(nb["attn_norm"], x)
-        q, k, v = L.attention_qkv(attn_p, h, positions, n_heads=cfg.n_heads,
-                                  n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
-                                  rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm)
+        q, k, v, r = _qkv(params, cfg, layer, h, positions)
         k_all = jnp.concatenate([k_prefix.astype(k.dtype), k], axis=1)
         v_all = jnp.concatenate([v_prefix.astype(v.dtype), v], axis=1)
-        o = L.chunked_causal_attention(q, k_all, v_all, k_prefix.shape[1])
-        x = x + o.reshape(*x.shape[:-1], cfg.n_heads * cfg.head_dim) @ attn_p["wo"]
-        h2 = L.rmsnorm(mlp_holder["mlp_norm"], x)
-        if kind == "moe_attn":
-            y, _ = L.moe(blk["moe"], h2, top_k=cfg.moe_top_k,
-                         capacity_factor=cfg.moe_capacity_factor)
-        else:
-            y = L.swiglu(mlp_holder["mlp"], h2)
-        return _act_constrain(x + y), k, v
+        o = L.chunked_causal_attention(q, k_all, v_all, k_prefix.shape[1],
+                                       cfg.window(layer))
+        x, _ = _finish(params, cfg, layer, x, o, r)
+        return _act_constrain(x), k, v
 
     # -- decode ------------------------------------------------------------
     def gather_context(self, dev_k, dev_v, slots, tail_k, tail_v, tail_fill):
@@ -349,26 +377,49 @@ class TransformerAdapter:
     def decode_block(self, params, layer, x, positions, k_ctx, v_ctx, ctx_mask,
                      use_pallas=False):
         cfg = self.cfg
-        kind = cfg.blocks[layer]
-        blk = params["blocks"][layer]
-        nb, attn_p, mlp_holder = _attn_params(params, cfg, layer)
+        nb, _, _ = _attn_params(params, cfg, layer)
         h = L.rmsnorm(nb["attn_norm"], x)
-        q, k_new, v_new = L.attention_qkv(
-            attn_p, h[:, None], positions[:, None], n_heads=cfg.n_heads,
-            n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
-            rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm)
+        q, k_new, v_new, r = _qkv(params, cfg, layer, h[:, None], positions[:, None])
         q, k_new, v_new = q[:, 0], k_new[:, 0], v_new[:, 0]
         o = L.decode_attention(q, k_ctx, v_ctx, ctx_mask, k_new, v_new,
                                use_pallas=use_pallas)
-        x = x + o.reshape(x.shape[0], cfg.n_heads * cfg.head_dim) @ attn_p["wo"]
-        h2 = L.rmsnorm(mlp_holder["mlp_norm"], x)
-        if kind == "moe_attn":
-            y, _ = L.moe(blk["moe"], h2[:, None], top_k=cfg.moe_top_k,
-                         capacity_factor=cfg.moe_capacity_factor)
-            y = y[:, 0]
-        else:
-            y = L.swiglu(mlp_holder["mlp"], h2)
-        return x + y, k_new, v_new
+        x, _ = _finish(params, cfg, layer, x, o, None if r is None else r[:, 0])
+        return x, k_new, v_new
+
+    @functools.partial(jax.jit, static_argnames=("self", "layer"),
+                       donate_argnames=("ring_k", "ring_v"))
+    def decode_window_block(self, params, layer, x, positions, ring_k, ring_v, active):
+        """One decode step of a windowed layer, in one program.
+
+        ``ring_k/ring_v [B, W, H_kv, d]`` hold each row's last ``W`` tokens,
+        the token at position ``p`` in slot ``p % W``.  The query at
+        ``positions`` attends the ring's slots that hold positions inside
+        its window, and itself; its K/V then replace the slot's oldest
+        token in the donated ring, for ``active`` rows only.  Returns
+        ``(x, ring_k, ring_v)``."""
+        cfg = self.cfg
+        nb, _, _ = _attn_params(params, cfg, layer)
+        h = L.rmsnorm(nb["attn_norm"], x)
+        q, k_new, v_new, r = _qkv(params, cfg, layer, h[:, None], positions[:, None])
+        q, k_new, v_new = q[:, 0], k_new[:, 0], v_new[:, 0]
+        w = ring_k.shape[1]
+        slot = positions % w
+        # slot s holds the latest position p = s (mod W) before the query:
+        # before the ring fills, the slots below the query's position; then
+        # every slot but the one about to be overwritten (p = query - W)
+        s_idx = jnp.arange(w)[None, :]
+        mask = jnp.where(positions[:, None] < w, s_idx < positions[:, None],
+                         s_idx != slot[:, None])
+        o = L.decode_attention(q, ring_k, ring_v, mask, k_new.astype(ring_k.dtype),
+                               v_new.astype(ring_v.dtype))
+        x, _ = _finish(params, cfg, layer, x, o, None if r is None else r[:, 0])
+        rows = jnp.arange(x.shape[0])
+        keep = active[:, None, None]
+        ring_k = ring_k.at[rows, slot].set(
+            jnp.where(keep, k_new.astype(ring_k.dtype), ring_k[rows, slot]))
+        ring_v = ring_v.at[rows, slot].set(
+            jnp.where(keep, v_new.astype(ring_v.dtype), ring_v[rows, slot]))
+        return x, ring_k, ring_v
 
     @functools.partial(jax.jit, static_argnames=("self", "layer"))
     def decode_state_block(self, params, layer, x, positions, state):
@@ -405,4 +456,6 @@ class TransformerAdapter:
         q = (h @ attn_p["wq"]).reshape(b, cfg.n_heads, cfg.head_dim)
         if cfg.qk_norm:
             q = L.rmsnorm(attn_p["q_norm"], q)
+        if layer in cfg.nope_layers:
+            return q
         return L.apply_rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]

@@ -77,6 +77,7 @@ _WALL_SPANS = {
     "fetch_wait": ("wait", "fetch"),
     "mirror_upload": ("upload", "attn"),
     "attn": ("attn", "attn"),
+    "window": ("window", "attn"),
     "prefetch": ("fetch", "prefetch"),
 }
 

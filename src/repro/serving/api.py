@@ -153,8 +153,7 @@ class ServeSession:
                  slots: int, calib_k: np.ndarray | None = None,
                  adapter=None, prefix_cache=None, obs=None,
                  faults=None, degrade: DegradationPolicy | None = None):
-        kinds = getattr(model, "layer_kinds", ("kv",) * model.n_layers)
-        if any(k != "kv" for k in kinds):
+        if "state" in getattr(model, "layer_kinds", ()):
             raise ValueError(
                 "ServeSession requires attention-only models: recurrent "
                 "state layers have no per-row admission/retirement")

@@ -92,6 +92,10 @@ def attach_kvswap_adapters(key, params, cfg, rank: int, dtype=jnp.float32):
 
 def init_cache(cfg, batch: int, max_len: int, *, dtype=jnp.float32,
                kvswap: KVSwapServeConfig | None = None):
+    if not _is_whisper(cfg) and (cfg.window_layers or cfg.nope_layers or cfg.router_before_attn):
+        raise NotImplementedError(
+            f"{cfg.name}: this path serves full rotary attention with the router after it; "
+            "windowed, NoPE and router-first layers are served by repro.core.engine")
     layers = []
     for kind in _blocks(cfg):
         if kind in ATTN_KINDS:

@@ -132,3 +132,35 @@ def test_step_end_flush_compiles_at_qwen3_8b_widths(one_chip):
     klr_bytes = layers * b * cap * R * 4
     assert mem.alias_size_in_bytes >= klr_bytes
     assert mem.temp_size_in_bytes < klr_bytes // layers
+
+
+@pytest.mark.parametrize("program", ["decode_block", "decode_window_block"])
+def test_expert_decode_programs_compile_at_smallthinker_widths(one_chip, program):
+    """The full layer's ``decode_block`` and the windowed layers'
+    ``decode_window_block`` at SmallThinker-21B-A3B's widths (64 experts of
+    768, 6 active, routed through the grouped-matmul kernel), bfloat16, 8
+    rows; the 4096-token rings are donated and written in place."""
+    from repro.configs import smallthinker_21b_a3b
+    from repro.models.transformer import TransformerAdapter, init_params
+
+    cfg = dataclasses.replace(smallthinker_21b_a3b.config(), n_layers=4,
+                              block_pattern=("moe_attn",) * 4,
+                              window_layers=(0, 1, 2), nope_layers=(3,))
+    model = TransformerAdapter(cfg)
+    params = jax.tree.map(
+        lambda a: _shape(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)))
+    s = functools.partial(_shape, one_chip)
+    b, hk, d = 8, cfg.n_kv_heads, cfg.head_dim
+    x, pos = s((b, cfg.d_model), jnp.bfloat16), s((b,), jnp.int32)
+    if program == "decode_block":
+        ctx = s((b, M * G + G, hk, d), jnp.bfloat16)
+        c = model.decode_block.lower(model, params, 3, x, pos, ctx, ctx,
+                                     s((b, M * G + G), jnp.bool_)).compile()
+    else:
+        ring = s((b, cfg.sliding_window, hk, d), jnp.bfloat16)
+        c = model.decode_window_block.lower(model, params, 0, x, pos, ring, ring,
+                                            s((b,), jnp.bool_)).compile()
+        ring_bytes = 2 * b * cfg.sliding_window * hk * d * 2
+        assert c.memory_analysis().alias_size_in_bytes >= ring_bytes
+    assert _has_kernel(c)
