@@ -41,12 +41,14 @@ import dataclasses
 import itertools
 from typing import Callable, Iterator, Sequence
 
+import jax
 import numpy as np
 
 from repro.core.engine import EngineConfig, KVSwapEngine, summarize_steps
 from repro.faults.errors import StorageFault
 from repro.serving.errors import RequestRejected
-from repro.serving.sampling import SamplingParams, make_row_sampler
+from repro.serving.sampling import (SamplingParams, greedy, greedy_rows,
+                                    make_row_sampler)
 
 WAITING, RUNNING, DONE, FAILED = "waiting", "running", "done", "failed"
 
@@ -63,8 +65,8 @@ class Request:
     arrival: float = 0.0                # modeled seconds on the session clock
     slo_class: str = ""                 # trace-harness SLO class label
     tenant: str = ""                    # opaque tenant label (routing/affinity)
-    # raw ``logits [1, V] -> ids [1]`` override (BatchServer compatibility);
-    # prefer ``sampling`` for new code
+    # raw ``logits [1, V] -> ids [1]`` override (BatchServer compatibility),
+    # called on a host copy of the row; prefer ``sampling`` for new code
     sampler: Callable | None = dataclasses.field(default=None, repr=False)
     # filled in by the session — the full lifecycle on the modeled clock:
     #   arrival <= admitted_at <= first_token_at <= finished_at
@@ -130,10 +132,15 @@ class DegradationPolicy:
 class _Slot:
     """Runtime state of one engine row while a request occupies it."""
 
-    def __init__(self, req: Request, sampler: Callable, logits: np.ndarray):
+    def __init__(self, req: Request, sampler: Callable, logits: jax.Array):
         self.req = req
         self.sampler = sampler
-        self.logits = logits            # [1, V] current next-token logits
+        # the step's batched argmax stands in for the sampler only where the
+        # sampler is greedy itself
+        self.greedy = sampler is greedy
+        # [1, V] device logits of the row's own (admission, replay), or None
+        # while its next logits are its row of the last decode step's block
+        self.logits: jax.Array | None = logits
         self.out: list[int] = []
         self.stop_set = frozenset(int(t) for t in req.stop_ids)
 
@@ -185,6 +192,10 @@ class ServeSession:
         self._rid = itertools.count()
         self._waiting: list[Request] = []
         self._slots: list[_Slot | None] = [None] * slots
+        # the last decode step's [B, V] logits, kept on the device, and the
+        # greedy ids of its rows ([B] int32, dispatched behind the step)
+        self._step_logits: jax.Array | None = None
+        self._step_ids: jax.Array | None = None
 
     def _count(self, name: str, delta: float = 1) -> None:
         if self.obs.enabled:
@@ -309,8 +320,7 @@ RequestRejected` (a ``ValueError``) and count on
                 due.cached_tokens = rep["cached_tokens"]
                 due.restored_tokens = rep["cached_tokens"]
                 sampler = due.sampler or make_row_sampler(due.sampling)
-                self._slots[i] = _Slot(due, sampler,
-                                       np.asarray(logits)[None, :])
+                self._slots[i] = _Slot(due, sampler, logits[None])
                 events.append({"type": "admit", "rid": due.rid, "slot": i,
                                "t": self.now, "cached_tokens": due.cached_tokens})
 
@@ -410,14 +420,14 @@ RequestRejected` (a ``ValueError``) and count on
         the session clock: recovery is visible latency, not free.
         """
         req = slot.req
-        logits = np.asarray(self.engine.admit_row(i, req.prompt, None))
+        logits = self.engine.admit_row(i, req.prompt, None)[None]
         self.now += self.engine.prefill_report["modeled_seconds"]
         toks = np.zeros(self.n_slots, dtype=np.int64)
         for tok in slot.out:
             toks[i] = tok
-            logits = np.asarray(self.engine.decode_step(toks))[i]
+            logits = jax.lax.dynamic_slice_in_dim(self.engine.decode_step(toks), i, 1)
             self.now += self.engine.step_log[-1].pipelined_seconds
-        slot.logits = logits[None, :]
+        slot.logits = logits
         # mask the row back out so the next survivor replays alone;
         # _recover_from_decode_fault reactivates every survivor at the end
         self.engine.deactivate_row(i)
@@ -498,6 +508,16 @@ RequestRejected` (a ``ValueError``) and count on
                     self._degrade_level)
 
     # -- the scheduler iteration -----------------------------------------
+    def _sample_row(self, i: int, slot: _Slot) -> int:
+        """One row through its own sampler, on device logits; a raw
+        ``sampler=`` override gets a host copy of its row alone."""
+        logits = slot.logits
+        if logits is None:
+            logits = jax.lax.dynamic_slice_in_dim(self._step_logits, i, 1)
+        if slot.req.sampler is not None and not slot.greedy:
+            logits = np.asarray(logits)
+        return int(np.asarray(slot.sampler(logits)).reshape(-1)[0])
+
     def step(self) -> list[dict]:
         """One continuous-batching iteration; returns this step's events.
 
@@ -521,9 +541,19 @@ RequestRejected` (a ``ValueError``) and count on
             return events
         toks = np.zeros(self.n_slots, dtype=np.int64)
         with self.obs.phase("sample"):
+            # greedy rows of the last decode step: one [B] pull of the ids
+            # its argmax left; every other row calls its own sampler
+            ids = None if self._step_ids is None else np.asarray(self._step_ids)
+            self._step_ids = None
+            batched = calls = 0
             for i in self._active():
                 slot = self._slots[i]
-                tok = int(np.asarray(slot.sampler(slot.logits)).reshape(-1)[0])
+                if slot.logits is None and slot.greedy:
+                    tok = int(ids[i])
+                    batched += 1
+                else:
+                    tok = self._sample_row(i, slot)
+                    calls += 1
                 slot.out.append(tok)
                 if len(slot.out) == 1:
                     slot.req.first_token_at = self.now
@@ -536,6 +566,8 @@ RequestRejected` (a ``ValueError``) and count on
                     self._finish(i, events)
                 else:
                     toks[i] = tok
+        self._count("kvswap_sample_batched_rows_total", batched)
+        self._count("kvswap_sample_row_calls_total", calls)
         # slots freed above are refilled at the NEXT step's admission phase:
         # a request admitted now would join this decode step without having
         # sampled its first token (its logits come from the admission
@@ -544,9 +576,13 @@ RequestRejected` (a ``ValueError``) and count on
         if active:
             try:
                 logits = self.engine.decode_step(toks)
-                # the logits' trip to the host: waits for the step's programs
+                # queued behind the step's head; the next sample phase pulls
+                # only these ids
+                if any(self._slots[i].greedy for i in active):
+                    self._step_ids = greedy_rows(logits)
+                # the logits stay on the device: waits for the step's programs
                 with self.obs.phase("logits_pull"):
-                    logits = np.asarray(logits)
+                    jax.block_until_ready(logits)
             except StorageFault as exc:
                 # unrecoverable mid-step fault: fail the culprit request,
                 # replay the rest (docs/robustness.md rung 2) — the session
@@ -555,8 +591,9 @@ RequestRejected` (a ``ValueError``) and count on
                 return events
             self.now += self.engine.step_log[-1].pipelined_seconds
             self._note_step_latency(self.engine.step_log[-1].pipelined_seconds)
+            self._step_logits = logits
             for i in active:
-                self._slots[i].logits = logits[i:i + 1]
+                self._slots[i].logits = None
         return events
 
     def stream(self) -> Iterator[dict]:

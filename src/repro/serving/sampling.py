@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_argmax = jax.jit(lambda lg: jnp.argmax(lg, axis=-1))
-
 
 @dataclasses.dataclass(frozen=True)
 class SamplingParams:
@@ -55,13 +53,21 @@ def make_row_sampler(params: SamplingParams | None = None):
                         top_p=params.top_p, seed=params.seed)
 
 
+@jax.jit
+def greedy_rows(logits: jax.Array) -> jax.Array:
+    """``[B, V] -> [B]`` int32 argmax on the device: every greedy row of a
+    decode step's logits block in one program, so the host pulls ``B`` ids
+    and not the block.  A row's id is the one it gets alone."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def greedy(logits) -> np.ndarray:
-    return np.asarray(_argmax(logits))
+    return np.asarray(greedy_rows(logits))
 
 
 def greedy_device(logits) -> jax.Array:
     """Jitted argmax returning the device array (no per-token host pull)."""
-    return _argmax(logits)
+    return greedy_rows(logits)
 
 
 def make_sampler(*, temperature: float = 1.0, top_k: int = 0, top_p: float = 0.0,
