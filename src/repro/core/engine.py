@@ -84,6 +84,14 @@ from repro.io import DoubleBuffer, PrefetchWorker, ReadRun, ReadScheduler
 from repro.obs import NULL_OBS, PrefetchQualityMeter
 from repro.utils import stats as stats_util
 
+# PrefetchWorker pool size (async mode only)
+IO_THREADS = 2
+# bounded retry-with-backoff for disk reads (docs/robustness.md): total
+# attempts per coalesced run, exponential modeled backoff from IO_BACKOFF_S
+# between them (charged as accountant stall time, never slept)
+IO_MAX_ATTEMPTS = 3
+IO_BACKOFF_S = 0.002
+
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
@@ -126,7 +134,6 @@ class EngineConfig:
       device memory: ``2·C·G·H_kv·d·itemsize`` bytes per KV layer.
     * ``async_io`` — run group preloading on the background worker
       (:mod:`repro.io`); bit-identical tokens, overlapped wall-clock.
-    * ``io_threads`` — prefetch worker threads (async mode only).
     * ``coalesce_gap`` — largest unrequested-group gap the
       :class:`ReadScheduler` reads through to keep a request sequential;
       0 merges only strictly adjacent groups.
@@ -146,14 +153,7 @@ class EngineConfig:
     dtype: str = "float32"
     compute: str = "jetson-orin-agx"  # timing model for simulated throughput
     async_io: bool = False         # background prefetch pipeline (repro.io)
-    io_threads: int = 2            # PrefetchWorker pool size
     coalesce_gap: int = 0          # ReadScheduler gap coalescing (groups)
-    # bounded retry-with-backoff for disk reads (docs/robustness.md):
-    # io_max_attempts total attempts per coalesced run, exponential modeled
-    # backoff from io_backoff_s between them (charged as accountant stall
-    # time, never slept).  1 attempt = fail on first error.
-    io_max_attempts: int = 3
-    io_backoff_s: float = 0.002
 
     @property
     def disk_spec(self) -> DiskSpec:
@@ -466,8 +466,8 @@ class KVSwapEngine:
         # one retry policy shared by every manager (and publish): transient
         # disk faults are absorbed below the engine, surfacing only as
         # modeled stall seconds; exhaustion escalates as typed FetchFailed
-        self._retry = RetryPolicy(max_attempts=cfg.io_max_attempts,
-                                  backoff_base_s=cfg.io_backoff_s)
+        self._retry = RetryPolicy(max_attempts=IO_MAX_ATTEMPTS,
+                                  backoff_base_s=IO_BACKOFF_S)
         self.managers = [
             KVCacheManager(store=self.store, reuse=self.reuse[j], rolling=self.rolling[j],
                            layer=j, scheduler=self.scheduler, warm=self.warm,
@@ -477,14 +477,14 @@ class KVSwapEngine:
         self.prefetcher: PrefetchWorker | None = None
         if cfg.async_io:
             self.prefetcher = PrefetchWorker(
-                self._fetch_table, n_threads=cfg.io_threads,
+                self._fetch_table, n_threads=IO_THREADS,
                 max_pending=max(4, 2 * max(n_kv_layers, 1)),
                 accountant=self.accountant,
                 obs=self.obs,
             )
         # prefetch-quality meter: always on (host-side set arithmetic, pure
-        # observation) — its counts feed StepStats / summarize_steps and the
-        # benchmarks, with or without an obs handle
+        # observation) — its counts feed StepStats / summarize_steps, with
+        # or without an obs handle
         self.quality = PrefetchQualityMeter()
         # recurrent state for non-KV (SSM / xLSTM) layers
         self.states: dict[int, object] = {}
@@ -1644,7 +1644,7 @@ class KVSwapEngine:
         return self.batch / t if t > 0 else 0.0
 
     def overlap_report(self, skip: int = 1) -> dict:
-        """Mean per-step modeled + measured overlap (benchmarks / serving)."""
+        """Mean per-step modeled + measured overlap."""
         return summarize_steps(self.step_log[skip:] or self.step_log)
 
     def close(self):

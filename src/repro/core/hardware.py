@@ -1,8 +1,7 @@
 """Hardware constants + analytic compute-time model.
 
-These are *modeled* times, not measurements: the serving benchmarks price
-compute for the paper's evaluation platform (Jetson Orin AGX) and the dry-run
-roofline uses TPU v5e constants.  A time measured on a device comes from the
+These are *modeled* times, not measurements: the serving layer prices
+compute for the paper's evaluation platform (Jetson Orin AGX).  A time measured on a device comes from the
 host clock around work that ends in ``block_until_ready`` (``chip_smoke.py``).
 """
 
@@ -32,7 +31,7 @@ ORIN = ComputeSpec("jetson-orin-agx", peak_flops=10.6e12, mem_bw=204.8e9)
 # sit at realistic relative scales for a small model.
 ORIN_NANO = ComputeSpec("jetson-orin-nano", peak_flops=1.28e12, mem_bw=68e9)
 
-# TPU v5e (dry-run/roofline target): 197 TFLOP/s bf16, 819 GB/s HBM,
+# TPU v5e: 197 TFLOP/s bf16, 819 GB/s HBM,
 # ~50 GB/s per ICI link (constants fixed by the reproduction brief).
 TPU_V5E = ComputeSpec("tpu-v5e", peak_flops=197e12, mem_bw=819e9, link_bw=50e9)
 

@@ -4,8 +4,8 @@ Two pieces:
 
 * :class:`DiskSpec` — an analytic timing model of a block-granular storage
   device (NVMe / eMMC / UFS).  The container's physical disk is neither a
-  Jetson NVMe nor an eMMC part, so throughput numbers in the benchmarks are
-  *modeled* from this spec, calibrated against the paper's Fig. 2 bandwidth
+  Jetson NVMe nor an eMMC part, so the serving layer's modeled clock is
+  priced from this spec, calibrated against the paper's Fig. 2 bandwidth
   curve (effective BW < 6 % of peak at 512 B requests, approaching peak for
   >= 256 KiB requests).  Correctness always uses the real store below.
 

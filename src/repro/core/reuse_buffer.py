@@ -72,7 +72,7 @@ class DeviceReuseMirror:
 
     ``uploaded_bytes`` counts the *payload* bytes shipped host→device (the
     groups the delta actually contains) — the transfer-counting hook the
-    tests and the ``decode_hotpath`` benchmark assert against.
+    tests assert against.
     ``padded_bytes`` additionally includes the zero rows the pow-2 bucket
     padding ships (a batching artifact: it buys a tiny, pre-compilable set
     of scatter shapes; the padding never exceeds one bucket of slack).

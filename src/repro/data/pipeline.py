@@ -8,7 +8,7 @@ No external datasets are available offline, so the pipeline provides:
   exactly (checkpointable input pipeline).
 * :class:`NeedleTask` — Needle-in-a-Haystack-style prompts (paper Fig. 9):
   a key token sequence is planted at a controlled depth inside filler; the
-  quality benchmarks check whether the KVSwap predictor keeps the needle's
+  ``examples/long_context_niah.py`` checks whether the KVSwap predictor keeps the needle's
   KV entries among the selected groups.
 * ``calib_k_cache`` — calibration K-cache sampler for the offline SVD
   (paper App. A.1 uses C4/WikiText samples; here: the model's own K outputs

@@ -24,8 +24,7 @@ Usage::
     tokens = front.result(rid)
 
 See docs/architecture.md ("Disaggregated serving") for the ticket
-lifecycle and the tier-chain walk, docs/tuning.md for the knobs, and
-``benchmarks/disagg_serving.py`` for the TPOT-under-burst headline.
+lifecycle and the tier-chain walk, and docs/tuning.md for the knobs.
 """
 
 from repro.disagg.frontend import DisaggFrontEnd

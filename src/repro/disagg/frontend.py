@@ -34,7 +34,8 @@ Bit-identity: the decode session recomputes admission logits from the
 restored prefix exactly as a cold prefill would (the cache's restore
 contract at ``kv_bits=16``), and a request's token stream depends only on
 its own prompt + sampling — so disaggregated tokens equal co-located and
-solo tokens per request.  ``benchmarks/disagg_serving.py`` asserts this.
+solo tokens per request
+(``tests/test_disagg.py::TestHandoffPipeline::test_tokens_bit_identical_to_solo``).
 """
 
 from __future__ import annotations
@@ -45,7 +46,7 @@ from typing import Mapping
 import numpy as np
 
 from repro.disagg.prefill import PrefillEngine
-from repro.disagg.ticket import (ADMITTED, DONE, FAILED, QUEUED, READY,
+from repro.disagg.ticket import (ADMITTED, DONE, FAILED, READY,
                                  PrefillTicket)
 from repro.obs import NULL_OBS
 from repro.router.frontend import parse_request

@@ -113,6 +113,6 @@ class InjectedCrash(StorageFault):
 
     Simulates dying mid-operation (e.g. a torn manifest write): the
     injection site leaves on-disk state exactly as a real crash would,
-    then raises this instead of ``os._exit`` so tests and benchmarks can
+    then raises this instead of ``os._exit`` so tests can
     observe the recovery path in-process.
     """

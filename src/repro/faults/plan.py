@@ -15,7 +15,7 @@ finally succeeds.  Two properties follow, and both are load-bearing:
   after which attempts succeed.  Keep ``error_burst <
   RetryPolicy.max_attempts`` and every transient fault is recovered
   in-place by retries — which is exactly the configuration under which
-  ``benchmarks/fault_injection.py`` asserts tokens stay bit-identical.
+  ``tests/test_faults.py`` asserts tokens stay bit-identical.
   Set ``error_burst`` at/above the retry budget and the same machinery
   produces persistent-looking failures that exercise the escalation
   ladder instead.

@@ -93,7 +93,7 @@ class ReadScheduler:
         return runs
 
     def stats(self, plan: Sequence[ReadRun]) -> dict:
-        """Summary counters for a plan (used by tests and benchmarks)."""
+        """Summary counters for a plan (used by tests)."""
         return {
             "requests": len(plan),
             "groups_requested": sum(len(r.ids) for r in plan),

@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache for this repository's entry points.
 
 Called from the ``__main__`` block of ``chip_smoke.py``, ``launch/serve.py``
-and the benchmarks, never on import, so a library user or a test process
+and ``bench/run.py``, never on import, so a library user or a test process
 keeps whatever cache configuration it has.
 """
 

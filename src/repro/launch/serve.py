@@ -2,8 +2,8 @@
 
 * ``--engine disk``   — the paper's runtime: KV on disk, grouped prediction,
   reuse buffer, modeled Jetson+NVMe/eMMC timing (repro.core).
-* ``--engine device`` — the TPU-native path the dry-run lowers: device cache
-  + KVSwap selected attention (repro.serving.decode).
+* ``--engine device`` — the device-cache path: device KV cache + KVSwap
+  selected attention (repro.serving.decode).
 
     PYTHONPATH=src python -m repro.launch.serve --arch llama3-8b --smoke \
         --engine disk --prompt-len 96 --gen-len 32

@@ -7,8 +7,6 @@ partition rules over param path names (repro.sharding).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -284,27 +282,6 @@ def gelu_mlp(p, x: jax.Array) -> jax.Array:
 # Mixture of Experts (GShard-style dispatch/combine; expert axis shardable)
 # --------------------------------------------------------------------------
 
-# Optional activation-sharding annotations for the MoE dispatch path.  Set by
-# the launcher (inside a mesh context) via ``set_moe_pspecs``; None disables
-# (single-device tests).  Without these, GSPMD is free to replicate the
-# per-expert buffer and all-reduce [B,E,C,F] partials — catastrophic at pod
-# scale (observed 33 TB/device of all-reduce on llama4 prefill).  Pinning the
-# buffer to P(batch→data, expert→model) turns dispatch into the canonical
-# token all-to-all instead.
-_MOE_PSPECS: dict | None = None
-
-
-def set_moe_pspecs(specs: dict | None) -> None:
-    """``specs = {"buf": P(dp, "model", None, None), "y": P(dp, None, None)}``."""
-    global _MOE_PSPECS
-    _MOE_PSPECS = specs
-
-
-def _moe_constrain(name: str, x: jax.Array) -> jax.Array:
-    if _MOE_PSPECS is None or name not in _MOE_PSPECS:
-        return x
-    return jax.lax.with_sharding_constraint(x, _MOE_PSPECS[name])
-
 def init_moe(key, *, d_model: int, d_ff: int, n_experts: int, dtype=jnp.float32,
              shared_d_ff: int = 0):
     ks = jax.random.split(key, 5)
@@ -384,17 +361,15 @@ def _moe_capacity(p, x, gate_vals, gate_idx, act, capacity_factor):
     bidx = jnp.arange(b)[:, None].repeat(t, 1)
     buf = jnp.zeros((b, e, cap, d), x.dtype)
     buf = buf.at[bidx, expert_of, pos_safe].set(x_tok, mode="drop")
-    buf = _moe_constrain("buf", buf)          # [B(data), E(model), C, D]
 
     h = act(jnp.einsum("becd,edf->becf", buf, p["w_gate"]))
     h = h * jnp.einsum("becd,edf->becf", buf, p["w_up"])
     out = jnp.einsum("becf,efd->becd", h, p["w_down"])     # [B,E,C,D]
-    out = _moe_constrain("buf", out)
 
     y_tok = out[bidx, expert_of, pos_safe.clip(0, cap - 1)]            # [B,T,D]
     y_tok = y_tok * (gates * keep)[..., None]
     y = jnp.zeros_like(x).at[bidx, token_of].add(y_tok)
-    return _moe_constrain("y", y).reshape(shape)
+    return y.reshape(shape)
 
 
 def _moe_dropless(p, x, gate_vals, gate_idx, act):

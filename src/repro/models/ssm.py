@@ -3,7 +3,7 @@
 These are the attention-free families among the assigned architectures.  They
 carry O(1) decode state, so KVSwap's disk offloading is inapplicable to them
 (see DESIGN.md §Arch-applicability) — but they must be first-class citizens of
-the serving/training stack and the multi-pod dry-run.
+the serving/training stack.
 
 Mamba2 uses the chunked SSD formulation (quadratic within a chunk, linear
 scan across chunks) so prefill at 32K lowers without materializing per-step

@@ -20,24 +20,6 @@ from repro.models import ssm as S
 ATTN_KINDS = ("attn", "moe_attn", "shared_attn")
 STATE_KINDS = ("mamba2", "mlstm", "slstm")
 
-# Optional between-block activation sharding (sequence parallelism): set by
-# the launcher inside a mesh context.  Constraining x to P(data, model, None)
-# between blocks lets GSPMD replace each TP all-reduce with a
-# reduce-scatter + all-gather pair — half the collective bytes.
-_ACT_PSPEC = None
-
-
-def set_activation_pspec(spec) -> None:
-    global _ACT_PSPEC
-    _ACT_PSPEC = spec
-
-
-def _act_constrain(x):
-    if _ACT_PSPEC is None or x.ndim != 3:
-        return x
-    return jax.lax.with_sharding_constraint(x, _ACT_PSPEC)
-
-
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -79,43 +61,6 @@ class ModelConfig:
     def window(self, layer: int) -> int:
         """Keys ``layer``'s query sees, its own included (0: every earlier one)."""
         return self.sliding_window if layer in self.window_layers else 0
-
-    def param_count(self) -> int:
-        """Analytic parameter count (for 6·N·D roofline bookkeeping)."""
-        d, hd = self.d_model, self.head_dim
-        n = self.vocab_size * d                       # embed (tied head)
-        if not self.tie_embeddings:
-            n += self.vocab_size * d
-        for kind in self.blocks:
-            if kind in ("attn", "moe_attn"):
-                n += d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd \
-                    + self.n_heads * hd * d
-                if kind == "attn":
-                    n += 3 * d * self.d_ff
-                else:
-                    n += d * self.n_experts + 3 * self.n_experts * d * self.moe_d_ff
-                    n += 3 * d * self.moe_shared_d_ff
-            elif kind == "shared_attn":
-                pass  # weights shared; counted once below
-            elif kind == "mamba2":
-                di = self.ssm_expand * d
-                n += d * (2 * di + 2 * self.ssm_state + di // 64) + di * d
-            elif kind in ("mlstm", "slstm"):
-                n += 4 * d * d if kind == "mlstm" else 8 * d * d + d * d
-        if "shared_attn" in self.blocks:
-            n += d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd \
-                + self.n_heads * hd * d + 3 * d * self.d_ff
-        return n
-
-    def active_param_count(self) -> int:
-        """Active params per token (MoE: top-k experts only)."""
-        if self.n_experts == 0:
-            return self.param_count()
-        full = self.param_count()
-        moe_layers = sum(1 for k in self.blocks if k == "moe_attn")
-        all_exp = moe_layers * 3 * self.n_experts * self.d_model * self.moe_d_ff
-        act_exp = moe_layers * 3 * self.moe_top_k * self.d_model * self.moe_d_ff
-        return full - all_exp + act_exp
 
 
 # --------------------------------------------------------------------------
@@ -240,7 +185,6 @@ def block_forward(params, cfg: ModelConfig, layer: int, x, positions, state=None
         q, k, v, r = _qkv(params, cfg, layer, h, positions)
         o = L.causal_attention(q, k, v, cfg.window(layer))
         x, aux = _finish(params, cfg, layer, x, o, r)
-        x = _act_constrain(x)
         return x, aux, ((k, v) if return_kv else None)
     # state blocks
     h = L.rmsnorm(blk["norm"], x)
@@ -355,7 +299,7 @@ class TransformerAdapter:
         o = L.chunked_causal_attention(q, k_all, v_all, k_prefix.shape[1],
                                        cfg.window(layer))
         x, _ = _finish(params, cfg, layer, x, o, r)
-        return _act_constrain(x), k, v
+        return x, k, v
 
     # -- decode ------------------------------------------------------------
     def gather_context(self, dev_k, dev_v, slots, tail_k, tail_v, tail_fill):

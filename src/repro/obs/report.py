@@ -1,8 +1,7 @@
 """Render an exported Perfetto trace into latency-breakdown tables.
 
-The benchmarks hand-roll per-step latency tables from ``overlap_report``;
-this CLI derives the same breakdown from a trace file instead, so any
-exported run — benchmark, test, or ad-hoc session — can be inspected
+Derives the per-step breakdown ``overlap_report`` gives from a trace file
+instead, so any exported run — test or ad-hoc session — can be inspected
 without rerunning it::
 
     PYTHONPATH=src python -m repro.obs.report trace.json

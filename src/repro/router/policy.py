@@ -59,7 +59,7 @@ class RoutingPolicy:
 
 class RoundRobin(RoutingPolicy):
     """Cycle through candidates in pool order, ignoring all signals —
-    the baseline the affinity benchmark measures against."""
+    the baseline affinity routing is tested against."""
 
     name = "round_robin"
 

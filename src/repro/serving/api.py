@@ -23,8 +23,8 @@ lifecycle.  This module is that front end for the KVSwap runtime:
 Time is **modeled** (the DiskSpec/ComputeSpec accountants): the session
 clock advances by each admission's modeled prefill seconds and each decode
 step's pipelined seconds, and requests carry an ``arrival`` timestamp on
-that clock — which is what lets a benchmark drive a Poisson arrival trace
-deterministically (``benchmarks/continuous_serving.py``).
+that clock — which is what lets a Poisson arrival trace replay
+deterministically (``tests/test_serving_api.py::TestSessionMechanics``).
 
 Determinism contract: a request's token stream depends only on its own
 prompt and sampling state — never on which slot it lands in, who shares the
@@ -44,7 +44,7 @@ from typing import Callable, Iterator, Sequence
 import jax
 import numpy as np
 
-from repro.core.engine import EngineConfig, KVSwapEngine, summarize_steps
+from repro.core.engine import EngineConfig, KVSwapEngine
 from repro.faults.errors import StorageFault
 from repro.serving.errors import RequestRejected
 from repro.serving.sampling import (SamplingParams, greedy, greedy_rows,
@@ -82,7 +82,7 @@ class Request:
     finished_at: float | None = None
     cached_tokens: int = 0              # prompt tokens restored from the cache
     # admission-time warm-restore coverage, surfaced in request_record so a
-    # harness (e.g. the disagg benchmark) can assert per-request restore
+    # harness (e.g. the disagg tests) can assert per-request restore
     # coverage without reaching into the engine.  Today identical to
     # cached_tokens at admission; kept separate because cached_tokens is
     # also the historical knob external callers mutate.
@@ -636,7 +636,7 @@ RequestRejected` (a ``ValueError``) and count on
 
     def stats(self) -> dict:
         """Session-cumulative serving stats (goodput = completed-request
-        tokens per modeled second — the benchmark's headline metric)."""
+        tokens per modeled second)."""
         done = list(self.completed.values())
         tokens = sum(len(r.output) for r in done)
         prompt_tokens = sum(len(r.prompt) for r in done)

@@ -13,8 +13,7 @@ Two serve modes:
 * ``kvswap`` — score against the compressed ``k_lr`` (Eq. 1, head-summed),
   ReduceMax over groups of G, top-M groups gathered and attended.
 
-``serve_step`` is functional: takes + returns the cache pytree, so it jits
-and lowers under pjit for the multi-pod dry-run.
+``serve_step`` is functional: takes + returns the cache pytree, so it jits.
 """
 
 from __future__ import annotations
@@ -70,8 +69,8 @@ def attach_kvswap_adapters(key, params, cfg, rank: int, dtype=jnp.float32):
     """Add per-KV-layer low-rank adapters ``A [H_k·d, r]`` to the params tree.
 
     In production these come from offline SVD (repro.core.lowrank.fit_adapter)
-    on calibration data; random orthonormal init keeps the dry-run honest
-    (same shapes/flops) without calibration data.
+    on calibration data; random orthonormal init keeps the same shapes and
+    flops without calibration data.
     """
     feat = cfg.n_kv_heads * cfg.head_dim
     n_kv = sum(1 for k in _blocks(cfg) if k in ATTN_KINDS)

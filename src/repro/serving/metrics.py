@@ -4,7 +4,7 @@ SLO attainment, goodput-under-SLO.
 :func:`repro.core.engine.summarize_steps` aggregates *per-step* engine
 accounting; nothing in the repo aggregated *per-request* latency until the
 trace harness needed it.  This module is that single aggregation path —
-the SLO benchmark, ``ServeSession.per_request()`` and any future routing
+trace replay, ``ServeSession.per_request()`` and any future routing
 work all report TTFT/TPOT/attainment through these helpers, so the numbers
 are comparable by construction.
 

@@ -7,7 +7,7 @@ truncating.  The serving API redesign moved the real machinery into
 :class:`repro.serving.api.ServeSession` — a **persistent** engine with
 per-slot admission/retirement — and :class:`BatchServer` survives as a thin
 wrapper that keeps the old surface (``submit``/``flush``/``result``/
-``last_stats``) for existing callers, benchmarks and examples:
+``last_stats``) for existing callers and examples:
 
 * one engine lives across flushes (jit caches, reuse buffers and the prefix
   cache all stay warm),

@@ -1,7 +1,7 @@
 """Versioned, seed-deterministic serving traces + replay.
 
-The serving benchmarks so far drive ad-hoc arrival patterns built inline;
-nothing is replayable across configs or PRs.  This module pins the workload
+Arrival patterns built inline are not replayable across configs or PRs.
+This module pins the workload
 as data: a **trace** is a JSONL file — one header line plus one line per
 request — that fully determines a serving run on the modeled clock, so the
 same file replayed through any :class:`~repro.serving.api.ServeSession`

@@ -1,6 +1,6 @@
-from repro.utils.bytesize import fmt_bytes, GiB, MiB, KiB
+from repro.utils.bytesize import GiB, MiB, KiB
 from repro.utils.stats import percentile, percentiles
 from repro.utils.treeops import tree_bytes, tree_count
 
-__all__ = ["fmt_bytes", "GiB", "MiB", "KiB", "percentile", "percentiles",
-           "tree_bytes", "tree_count"]
+__all__ = ["GiB", "MiB", "KiB", "percentile", "percentiles", "tree_bytes",
+           "tree_count"]

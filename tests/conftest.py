@@ -1,7 +1,7 @@
 import os
 import sys
 
-# tests must see exactly ONE device (the dry-run sets 512 itself)
+# tests must see exactly ONE device
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 

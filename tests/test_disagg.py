@@ -108,6 +108,14 @@ class TestHandoffPipeline:
         assert stats["prefix_hit_rate"] > 0
         assert stats["requeues"] == 0 and stats["ticket_failures"] == 0
 
+    def test_fault_free_run_sheds_and_fails_nothing(self, parts, prompts):
+        """With no fault planted and room at the boundary, no submission is
+        shed and no request fails."""
+        rids, _, agg, stats = run_front(parts, prompts)
+        assert stats["handoff_rejections"] == 0
+        assert stats["failed_requests"] == 0
+        assert len(agg["per_request"]) == len(rids)
+
     def test_restored_tokens_surfaced_per_request(self, parts, prompts):
         """Satellite: the decode admission's restore depth is visible in
         per-request stats, and equals exactly the prompt's published whole

@@ -5,7 +5,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 

@@ -23,8 +23,6 @@ concurrent requests), so the grid also covers the serving admission /
 retirement machinery, not just the static engine.
 """
 
-import dataclasses
-
 import numpy as np
 import pytest
 

@@ -471,6 +471,22 @@ class TestSessionUnderFaults:
                     req = sess.failed[rid]
                     assert req.state == FAILED and req.error
 
+    def test_persistent_faults_leave_prefetch_workers_alive(self, parts, rng):
+        """Bad extents escalate to failed requests on the async path, never
+        to a dead prefetch worker."""
+        prompts = [rng.integers(0, 97, 24) for _ in range(3)]
+        plan = FaultPlan(FaultSpec(seed=11, bad_extent_rate=0.35))
+        with make_session(parts, ecfg=make_ecfg(async_io=True),
+                          faults=plan) as sess:
+            rids = _run_trace(sess, prompts)
+            stats = sess.stats()
+            assert stats["failed_requests"] > 0
+            assert stats["failed_requests"] + stats["completed_requests"] \
+                == len(rids)
+            assert sess.engine.prefetcher.deaths == 0
+            assert sess.engine.prefetcher.alive_threads() == \
+                len(sess.engine.prefetcher._threads)
+
     def test_decode_fault_fails_culprit_and_replays_survivors(self, parts,
                                                               rng):
         """Force a FetchFailed mid-decode while two rows run: the culprit

@@ -160,6 +160,20 @@ class TestPolicies:
                 assert len(set(replicas)) == 1, \
                     f"tenant {tenant} sprayed across {set(replicas)}"
 
+    def test_affinity_hit_rate_beats_round_robin(self, setup):
+        """On a trace of multi-turn tenants, routing each turn to the
+        replica that cached the previous one serves more prompt tokens from
+        cache than cycling the pool."""
+        tr = tiny_mixed_trace()
+        hit = {}
+        for name, policy in (("rr", RoundRobin()),
+                             ("affinity", PrefixAffinityRouter())):
+            with fleet(setup, policy=policy) as front:
+                st = front.replay(tr)["fleet"]
+                assert st["completed_requests"] == tr.n_requests
+                hit[name] = st["prefix_hit_rate"]
+        assert hit["affinity"] > hit["rr"]
+
     def test_affinity_overload_penalty_repels(self, setup):
         """A degraded replica loses affinity units per ladder rung: even
         a fully-warm replica is out-scored by a cold idle one when it is

@@ -117,7 +117,7 @@ class TestTraceSchema:
                                           slo_classes=SLO)
         assert {r.tenant for r in tr.requests} == {"t0", "t1", "t2"}
         # per-tenant turns extend each other token-for-token (the
-        # prefix-affinity property the router benchmark leans on)
+        # prefix-affinity property the router leans on)
         by_tenant = {}
         for r in tr.requests:
             by_tenant.setdefault(r.tenant, []).append(r)
@@ -205,6 +205,25 @@ class TestReplay:
             m = replay(tiny_trace(), sess)
         assert 0.0 <= m["goodput_under_slo_tokens_per_s"] \
             <= m["goodput_tokens_per_s"] + 1e-12
+
+    def test_warm_prefix_replay_completes_with_bounded_attainment(self,
+                                                                  setup):
+        """The chat workload replayed through the full stack (int8 disk
+        tier, warm tier, prefix cache) completes every request, restores
+        cached tokens, and keeps every class's attainment in [0, 1]."""
+        from repro.cache import PrefixCache, PrefixCacheConfig
+
+        tr = chat_trace(7, conversations=2, turns=3, sys_tokens=24,
+                        user_tokens=8, max_new=4, turn_gap_s=1.0,
+                        slo_classes=SLO, vocab_size=97)
+        ecfg = make_cfg(kv_bits=8, warm_budget_bytes=1 << 20)
+        with PrefixCache(PrefixCacheConfig(block_tokens=8, kv_bits=8)) as pc:
+            with session(setup, ecfg, prefix_cache=pc) as sess:
+                m = replay(tr, sess)
+        assert m["requests"] == tr.n_requests
+        assert m["cached_prompt_tokens"] > 0
+        assert m["slo"] and all(0.0 <= b["attainment"] <= 1.0
+                                for b in m["slo"].values())
 
     def test_chat_replay_hits_prefix_cache(self, setup):
         """Replaying the chat workload through a prefix-cached session
